@@ -56,14 +56,7 @@ from .matgen import generate_matrix
 from .ops.f64emu import gemm_f64emu, gesv_f64ir, posv_f64ir
 from . import lapack_api
 from . import scalapack_api
-
-try:
-    # distributed layer needs jax.shard_map / NamedSharding; single-device use of
-    # the library must survive without it (blas.py raises a clear SlateError if a
-    # SUMMA method is requested while it is absent)
-    from . import parallel
-except ImportError:  # pragma: no cover - environment-specific
-    parallel = None
+from . import parallel
 
 __version__ = "0.2.0"
 VERSION = 2026_07_00   # yyyymmrr, the reference's integer form (version.cc)

@@ -9,9 +9,16 @@ consistency:
 * **coverage** — every replica group names valid participants and no device
   appears twice in one collective's groups (a duplicated id deadlocks the
   rendezvous);
-* **channel discipline** — no two distinct collective instructions share a
-  channel id (interleaved channel reuse is how mismatched schedules corrupt
-  each other's payloads);
+* **channel discipline** — in a cross-module (MPMD) program no two distinct
+  collective instructions share a channel id (interleaved channel reuse is
+  how mismatched schedules corrupt each other's payloads).  An SPMD-
+  partitioned module (header ``num_partitions`` > 1) is exempt: XLA 0.9
+  stamps ``channel_id=1`` on every cross-partition collective as a mode
+  marker, and every partition runs the one program, so the id pairs
+  nothing.  Every program JAX compiles for this library is such a module:
+  the check applies to none of them, only to hand-written MPMD modules
+  (tests/test_analysis.py's synthetic channel-reuse fixture).  On library
+  programs a corrupted schedule is caught by cross-participant agreement;
 * **uniform control flow** — no collective reachable only under a
   ``conditional`` branch (a ``lax.cond`` whose predicate diverges across
   participants leaves part of the mesh waiting at a rendezvous the rest
@@ -61,6 +68,9 @@ class CollectiveEvent:
     #: ``source_target_pairs`` for collective-permute (None otherwise):
     #: direction matters at the rendezvous, so it participates in identity
     pairs: Optional[Tuple[Tuple[int, int], ...]] = None
+    #: True when the owning module is SPMD-partitioned (``num_partitions``
+    #: > 1): its channel id is a cross-partition marker, not a pairing key
+    spmd: bool = False
 
     def participants(self, nproc: int) -> Tuple[int, ...]:
         if not self.groups:
@@ -213,6 +223,7 @@ def extract_events(hlo_text: str,
     if nproc is None:
         nproc = module_num_partitions(hlo_text) or _max_participant(comps) + 1
     uni = _UniformityAnalysis(comps, nproc)
+    spmd = (module_num_partitions(hlo_text) or 1) > 1
 
     def walk(comp: str, branch_path: Tuple[Tuple[str, int], ...],
              while_depth: int, uniform_so_far: bool,
@@ -234,7 +245,8 @@ def extract_events(hlo_text: str,
                     channel_id=ins.channel_id(), groups=groups,
                     branch_path=branch_path, while_depth=while_depth,
                     cond_uniform=bool(branch_path) and uniform_so_far,
-                    while_divergent=while_divergent, pairs=pairs))
+                    while_divergent=while_divergent, pairs=pairs,
+                    spmd=spmd))
             callees = ins.callees()
             if ins.opcode == "while":
                 div = while_divergent or \
@@ -480,7 +492,7 @@ def verify_events(events: Sequence[CollectiveEvent], nproc: int) -> List[str]:
             findings.append(
                 f"{ev.describe()}: device(s) {dups} appear in more than one "
                 "replica group of the same collective (rendezvous deadlock)")
-        if ev.channel_id is not None:
+        if ev.channel_id is not None and not ev.spmd:
             chan_sites.setdefault(ev.channel_id, []).append(
                 f"%{ev.name}@{ev.computation}")
         if ev.branch_path and not ev.cond_uniform:

@@ -394,7 +394,7 @@ def _options_key(ctx):
 # x64 + debug hygiene
 
 #: files allowed to flip process-global x64 (the tester entrypoint owns the
-#: process; everything else must use the scoped jax.experimental.enable_x64)
+#: process; everything else must use the scoped jax.enable_x64)
 X64_ALLOWED = ("slate_tpu/testing/__main__.py",)
 
 
@@ -402,7 +402,7 @@ X64_ALLOWED = ("slate_tpu/testing/__main__.py",)
 def _global_x64(ctx):
     """`jax.config.update("jax_enable_x64", ...)` flips precision for the
     whole process and leaks across sweep rows and library callers.  Use the
-    scoped `jax.experimental.enable_x64` context (testing/routines.py's
+    scoped `jax.enable_x64(True)` context (testing/routines.py's
     gesv_mixed shows the pattern); only the tester entrypoint may set the
     global."""
     if ctx.relpath in X64_ALLOWED:
@@ -419,7 +419,7 @@ def _global_x64(ctx):
                 "process-global jax_enable_x64 toggle outside the tester "
                 "entrypoint (leaks x64 across sweep rows and callers)",
                 suggestion="wrap the region in "
-                           "`with jax.experimental.enable_x64():`")
+                           "`with jax.enable_x64(True):`")
 
 
 @rule("SLT302", "warning", "leftover debug hook")

@@ -86,11 +86,8 @@ def gemm(alpha, A, B, beta, C, opts=None):
     method = select_algo_gemm(A, B, C, opts)
     if method == MethodGemm.SUMMA:
         # explicit shard_map pipeline; requires distributed wrappers
-        try:
-            from .parallel import summa
-        except ImportError as e:
-            raise SlateError("MethodGemm.SUMMA requires the distributed layer "
-                             "(slate_tpu.parallel)") from e
+        from .parallel import summa
+
         out = summa.summa_gemm(alpha, A, B, beta, C, opts)
     else:
         # stationary-A/C both lower to one fused MXU matmul on a single array;

@@ -115,7 +115,7 @@ def _chol_blocked(a):
     """Recursive blocked Cholesky of one diagonal block: factor the leading
     half, one triangular solve, one Schur-complement MXU gemm, recurse.  XLA's
     fused Cholesky serializes its internal panel recursion and crawls on large
-    blocks (BENCH_NOTES.md); the fused op runs only at the <=256 base."""
+    blocks (round-6 chip evidence); the fused op runs only at the <=256 base."""
     n = a.shape[-1]
     if n <= _CHOL_BASE:
         # lower-triangle-only reference (XLA Cholesky ignores the upper
@@ -279,6 +279,18 @@ def potrs(A, B, opts=None, uplo=None):
     F = as_array(A)
     L = jnp.tril(F) if the_uplo == Uplo.Lower else jnp.conj(jnp.triu(F).T)
     b = as_array(B)
+    grid = distribution_grid(A, B)
+    if grid is not None:
+        # grid-bound operands: the stationary-A sweeps (trsmA.cc; the factor
+        # never moves, nb x nrhs blocks of X travel).  XLA's own transposed
+        # TriangularSolve over a 2x2-sharded factor needs 17.6 GB per device
+        # at n=16384 (described-v5e compile), more than the chip holds.
+        from ..parallel.solvers import trsmA_distributed
+
+        with trace_block("potrs", grid=f"{grid.p}x{grid.q}"):
+            y = trsmA_distributed(L, b, grid, lower=True)
+            x = trsmA_distributed(L, y, grid, lower=True, conj_trans=True)
+        return write_back(B, x)
     with trace_block("potrs"):
         y = lax.linalg.triangular_solve(L, b, left_side=True, lower=True)
         x = lax.linalg.triangular_solve(L, y, left_side=True, lower=True,

@@ -231,11 +231,46 @@ def _getrf_tiled_fn(m: int, n: int, nb: int, dtype_str: str):
     return jax.jit(fn)
 
 
+#: XLA's TPU LU holds an (m, 128) column block of its panel, twice, in scoped
+#: VMEM (custom call ``LuDecompositionBlock``).  The scope, by device kind,
+#: where it was measured: on a v5e an m=16384 f32 LU asks for 16.07M of its
+#: 16 MiB and does not compile ("Ran out of memory in memory space vmem").
+#: Other TPU generations are not measured and keep partial pivoting.
+_XLA_LU_VMEM_SCOPE = {"TPU v5 lite": 16 * 2**20}
+
+
+def _operand_device(a):
+    """The device an operand sits on: its own for a concrete array; for a
+    traced one (or a host array), JAX's default device, where the program
+    runs unless its inputs are placed elsewhere."""
+    try:
+        return next(iter(a.devices()))
+    except (AttributeError, jax.errors.ConcretizationTypeError):
+        return jax.devices()[0]
+
+
+def _auto_method(A) -> MethodLU:
+    """MethodLU.Auto: partial pivoting through XLA's fused LU, except where
+    the operand's device kind is known to overflow that LU's VMEM scope --
+    there tournament pivoting (CALU), whose LUs run on nb-row leaves, is the
+    one that compiles.  Grid-bound operands keep their distributed route."""
+    a = as_array(A)
+    scope = _XLA_LU_VMEM_SCOPE.get(_operand_device(a).device_kind)
+    panel = 2 * a.shape[-2] * 128 * jnp.dtype(a.dtype).itemsize
+    if (scope is not None and distribution_grid(A) is None
+            and panel > scope - 2**20):
+        return MethodLU.CALU
+    return MethodLU.PartialPiv
+
+
 @instrument
 def getrf(A, opts=None):
-    """Partially-pivoted LU: returns (LU, perm, info) with A[perm] = L U
+    """Pivoted LU: returns (LU, perm, info) with A[perm] = L U
     (src/getrf.cc:22-260; dispatch over MethodLU like gesv's select_algo).
 
+    MethodLU.Auto is partial pivoting, except on a TPU whose XLA LU panel
+    would overflow its VMEM (a v5e at m >= 16384 f32): there it is tournament
+    pivoting, with its own pivots and growth bound (``_auto_method``).
     MethodLU.CALU routes to tournament pivoting (getrf_tntpiv), NoPiv to getrf_nopiv
     (perm = identity), RBT is reserved for gesv_rbt.
     """
@@ -246,7 +281,7 @@ def getrf(A, opts=None):
                  f"lu_panel must be 'tournament' or 'pp', got {opts.lu_panel!r}")
     method = opts.method_lu
     if method == MethodLU.Auto:
-        method = MethodLU.PartialPiv
+        method = _auto_method(A)
     if method == MethodLU.NoPiv:
         lu_, info = getrf_nopiv(A, opts)
         return lu_, jnp.arange(as_array(A).shape[-2]), info
@@ -522,6 +557,8 @@ def getrs_nopiv(LU, B, opts=None, trans=False):
 def gesv(A, B, opts=None):
     """Solve A X = B (src/gesv.cc = getrf + getrs).
 
+    The factorization is ``getrf``'s: with MethodLU.Auto, tournament
+    pivoting (CALU) where the operand's TPU cannot compile XLA's LU panel.
     Returns (X, perm, info); with ``Options(solve_report=True)``,
     (X, perm, info, SolveReport)."""
     opts = Options.make(opts)

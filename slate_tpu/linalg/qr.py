@@ -248,7 +248,7 @@ def cholqr(A, opts=None):
 
     def one_pass(x):
         # herk-halved Gram + recursive blocked factor of the n x n result
-        # (the fused XLA Cholesky serializes at large n, BENCH_NOTES.md)
+        # (the fused XLA Cholesky serializes at large n)
         G = gram(x)
         L = _chol_blocked(G)
         info = _chol_info(L)

@@ -15,7 +15,7 @@ any matrix shape; TPU executes the grid sequentially with the last dimension
 innermost, which the accumulation predicates rely on.  Zero padding is safe for
 every reduction here (|0| contributes nothing to max of abs, sums, or squares).
 
-On non-TPU backends the same kernels run through the Pallas interpreter
+On the CPU test backend the same kernels run through the Pallas interpreter
 (``interpret=True``) so CPU tests exercise the identical code path.
 """
 
@@ -26,15 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # both pallas and its TPU backend are optional: a jax build without
-    # pallas must not break `import slate_tpu` (the XLA norm path needs none)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover - environment-specific
-    pl = None
-    pltpu = None
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 _LANE = 128          # TPU lane width: last dim must be a multiple
 _SUBLANE = 8         # f32 sublane count: the native vreg tile is (8, 128), so
@@ -54,12 +46,10 @@ _MODE_LOWER_STRICT = 3   # keep r > c
 _MODE_UPPER_STRICT = 4   # keep r < c
 
 
-def available() -> bool:
-    return _HAS_PALLAS
-
-
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    # the interpreter is for the CPU test backend only; on a TPU backend the
+    # kernels always compile through Mosaic
+    return jax.default_backend() == "cpu"
 
 
 def _ceil_mult(x: int, m: int) -> int:
@@ -347,7 +337,9 @@ def traced_plan(m: int, n: int, dtype=jnp.float32, kind: str = "col") -> dict:
         raise RuntimeError("no pallas_call in traced norm kernel")
     gm = eqn.params["grid_mapping"]
     grid = tuple(gm.grid)
-    blocks = [tuple(b.block_shape) for b in gm.block_mappings]
+    # JAX 0.9 block shapes hold Blocked(block_size=...) entries, not ints
+    blocks = [tuple(getattr(d, "block_size", d) for d in b.block_shape)
+              for b in gm.block_mappings]
     # evaluate the INPUT block index_map over the whole grid: bijective
     # coverage == one streaming pass over HBM
     cj = gm.block_mappings[0].index_map_jaxpr

@@ -40,7 +40,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..core.exceptions import slate_assert
 from .distribute import ceil_mult
-from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS, shard_map
+from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS
 from ..obs import instrument
 
 AX = (ROW_AXIS, COL_AXIS)
@@ -186,7 +186,7 @@ def _pbtrf_dist_fn(mesh, npad: int, kd: int, nb: int, dtype_str: str):
         return lax.fori_loop(0, nt, body, Ab_loc)
 
     spec = P(None, AX)
-    fn = shard_map(local_fn, mesh=mesh, in_specs=spec, out_specs=spec,
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=spec, out_specs=spec,
                        check_vma=False)
     return jax.jit(fn)
 
@@ -279,7 +279,7 @@ def _tbsm_dist_fn(mesh, npad: int, kd: int, nb: int, nrhs: int,
 
         return lax.fori_loop(0, nt, body, B_loc)
 
-    fn = shard_map(local_fn, mesh=mesh,
+    fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(P(None, AX), P(AX, None)),
                        out_specs=P(AX, None), check_vma=False)
     return jax.jit(fn)
@@ -457,7 +457,7 @@ def _gbtrf_dist_fn(mesh, npad: int, kl: int, ku: int, nb: int,
         Gb_loc, perms = lax.fori_loop(0, nt, body, (Gb_loc, perms0))
         return Gb_loc, perms
 
-    fn = shard_map(local_fn, mesh=mesh, in_specs=P(None, AX),
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=P(None, AX),
                        out_specs=(P(None, AX), P(None, None)),
                        check_vma=False)
     return jax.jit(fn)
@@ -529,7 +529,7 @@ def _gbtrs_fwd_dist_fn(mesh, npad: int, kl: int, ku: int, nb: int, nrhs: int,
 
         return lax.fori_loop(0, nt, body, B_loc)
 
-    fn = shard_map(local_fn, mesh=mesh,
+    fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(P(None, AX), P(None, None), P(AX, None)),
                        out_specs=P(AX, None), check_vma=False)
     return jax.jit(fn)
@@ -568,7 +568,7 @@ def _gbtrs_bwd_dist_fn(mesh, npad: int, kl: int, ku: int, nb: int, nrhs: int,
 
         return lax.fori_loop(0, nt, body, B_loc)
 
-    fn = shard_map(local_fn, mesh=mesh,
+    fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(P(None, AX), P(AX, None)),
                        out_specs=P(AX, None), check_vma=False)
     return jax.jit(fn)

@@ -24,7 +24,7 @@ from ..core.exceptions import slate_assert
 from ..linalg.chol import posv_core
 from ..linalg.lu import gesv_core
 from ..obs import instrument
-from .mesh import COL_AXIS, ROW_AXIS, ProcessGrid, shard_map
+from .mesh import COL_AXIS, ROW_AXIS, ProcessGrid
 
 
 def _batch_sharded(core, grid: ProcessGrid, a, b, n_out: int):
@@ -39,7 +39,7 @@ def _batch_sharded(core, grid: ProcessGrid, a, b, n_out: int):
                  f"(pad the batch to a multiple — serve.BucketPolicy's "
                  f"batch rounding does)")
     spec = PartitionSpec((ROW_AXIS, COL_AXIS))
-    fn = shard_map(lambda al, bl: jax.vmap(core)(al, bl),
+    fn = jax.shard_map(lambda al, bl: jax.vmap(core)(al, bl),
                    mesh=grid.mesh,
                    in_specs=(spec, spec),
                    out_specs=tuple([spec] * n_out),

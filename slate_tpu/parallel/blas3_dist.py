@@ -38,7 +38,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.exceptions import slate_assert
 from .distribute import lcm, pad2d
-from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS, shard_map
+from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS
 from ..obs import instrument
 
 _PREC = lax.Precision.HIGHEST
@@ -108,7 +108,7 @@ def _rank_k_fn(mesh, n: int, lower: bool, herm: bool, two: bool):
         mask = _tri_mask(n // p, n // q, lower)
         return jnp.where(mask, upd + beta * c, c)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(ROW_AXIS, COL_AXIS), P(ROW_AXIS, COL_AXIS),
                   P(ROW_AXIS, COL_AXIS), P(), P()),

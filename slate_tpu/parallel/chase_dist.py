@@ -53,7 +53,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..core.exceptions import slate_assert
-from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS, shard_map
+from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS
 from ..obs import instrument
 
 AX = (ROW_AXIS, COL_AXIS)                  # flattened device axis
@@ -226,7 +226,7 @@ def _chase_dist_fn(mesh, n: int, b: int, seg: int, want_vectors: bool,
         return d_loc, e_loc, Vs, taus
 
     out_specs = (P(AX), P(AX), P(None), P(None))
-    fn = shard_map(local_fn, mesh=mesh, in_specs=(P(AX, None),),
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=(P(AX, None),),
                        out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
@@ -408,7 +408,7 @@ def _tb2bd_dist_fn(mesh, n: int, b: int, seg: int, want_vectors: bool,
         return d_loc, e_loc, Us, tauus, Vs, tauvs
 
     out_specs = (P(AX), P(AX), P(None), P(None), P(None), P(None))
-    fn = shard_map(local_fn, mesh=mesh, in_specs=(P(AX, None),),
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=(P(AX, None),),
                        out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS, shard_map
+from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS
 from ..obs import instrument
 
 
@@ -115,7 +115,7 @@ def _he2hb_shard_fn(mesh, npad: int, nb: int, dtype_str: str):
             jnp.zeros_like(A_loc))
         return band_loc, Vs_loc, Ts
 
-    fn = shard_map(local_fn, mesh=mesh, in_specs=P(AX, None),
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=P(AX, None),
                        out_specs=(P(AX, None), P(None, AX, None), P(None)),
                        check_vma=False)
     return jax.jit(fn)
@@ -144,7 +144,7 @@ def _unmtr_he2hb_shard_fn(mesh, npad: int, ncols: int, nb: int, nj: int,
 
         return lax.fori_loop(0, nj, body, C_loc)
 
-    fn = shard_map(local_fn, mesh=mesh,
+    fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(P(None, AX, None), P(None), P(AX, None)),
                        out_specs=P(AX, None), check_vma=False)
     return jax.jit(fn)
@@ -357,7 +357,7 @@ def _ge2tb_shard_fn(mesh, mpad: int, npc: int, nreal: int, nb: int,
             jnp.zeros_like(A_loc))
         return band_loc, Vu_loc, Tu, Vv, Tv
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh, in_specs=P(AX, None),
         out_specs=(P(AX, None), P(None, AX, None), P(None),
                    P(None, AX, None), P(None)),
@@ -587,7 +587,7 @@ def _hb2st_q_shard_fn(mesh, n: int, npad: int):
         q = sweep_accumulate(Vs, taus, n, Vs.shape[-1], Q0=q0)
         return q * phase[None, :]
 
-    fn = shard_map(local_fn, mesh=mesh,
+    fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(P(None), P(None), P(None)),
                        out_specs=P(AX, None), check_vma=False)
     return jax.jit(fn)
@@ -630,7 +630,7 @@ def _steqr_shard_fn(mesh):
     def local_fn(d, e, z_loc):
         return steqr_qr(d, e, z_loc)
 
-    fn = shard_map(local_fn, mesh=mesh,
+    fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(P(None), P(None), P(AX, None)),
                        out_specs=(P(None), P(AX, None)), check_vma=False)
     return jax.jit(fn)

@@ -43,7 +43,7 @@ from ..core.exceptions import slate_assert
 from .band_dist import (BandLUDist, dense_to_band_general, gbtrf_distributed,
                         gbtrs_distributed)
 from .distribute import ceil_mult
-from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS, shard_map
+from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS
 from .pivot import (exchange_rows as _exchange_rows,
                     extract_rows as _extract_rows,
                     step_permutation, tournament_piv)
@@ -270,7 +270,7 @@ def _hetrf_dist_fn(mesh, npad: int, nb: int, dtype_str: str):
         return L_loc, T_loc, perm
 
     spec = P(AX, None)
-    fn = shard_map(local_fn, mesh=mesh, in_specs=spec,
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=spec,
                        out_specs=(spec, spec, P(None)), check_vma=False)
     return jax.jit(fn)
 

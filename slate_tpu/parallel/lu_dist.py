@@ -50,7 +50,7 @@ from ..core.exceptions import slate_assert
 from ..robust import RetryPolicy, first_bad_index, guard_shards, inject
 from ..utils.trace import trace_event
 from .distribute import ceil_mult, lcm as _lcm
-from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS, shard_map
+from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS
 from .pivot import (exchange_rows as _exchange_rows,
                     select_pivots, step_permutation)
 from ..obs import instrument
@@ -194,7 +194,7 @@ def _getrf_dist_fn(mesh, npad: int, nb: int, dtype_str: str,
     # perm/info are computed identically on every shard (their inputs are all
     # psum/all_gather results), but the vma system cannot prove replication
     # through the swap fori_loops — the unsharded out_specs assert it.
-    fn = shard_map(local_fn, mesh=mesh, in_specs=spec,
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=spec,
                        out_specs=(spec, P(None), P()), check_vma=False)
     return jax.jit(fn)
 
@@ -308,7 +308,7 @@ def _getrf_tall_fn(mesh, mpad: int, npc: int, nb: int, dtype_str: str,
         return A_loc, perm, info
 
     spec = P(AX, None)
-    fn = shard_map(local_fn, mesh=mesh, in_specs=spec,
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=spec,
                        out_specs=(spec, P(None), P()), check_vma=False)
     return jax.jit(fn)
 

@@ -27,26 +27,6 @@ from ..core.types import GridOrder
 ROW_AXIS = "p"
 COL_AXIS = "q"
 
-# --- environment resilience: the distributed layer is written against the
-# modern ``jax.shard_map`` spelling (jax >= 0.5).  Older jax ships it at
-# ``jax.experimental.shard_map`` with ``check_rep`` instead of ``check_vma``;
-# without this adapter every shard_map driver dies with AttributeError at
-# first call on such environments.  The adapter is a module-local binding
-# (``from .mesh import shard_map``), NOT a patch of the global jax namespace —
-# mutating ``jax.shard_map`` would change what third-party feature detection
-# sees after ``import slate_tpu``.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - jax-version-specific
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=True, **kw):
-        kw.pop("check_rep", None)   # accept either spelling, pass one
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma,
-                                 **kw)
-
 
 class ProcessGrid:
     """A p×q grid of devices playing the role of the reference's MPI process grid.

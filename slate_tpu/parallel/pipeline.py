@@ -40,7 +40,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import numpy as np
 
-from .mesh import ProcessGrid, shard_map
+from .mesh import ProcessGrid
 from ..linalg.chol import _chol_blocked
 from ..obs import instrument
 
@@ -123,7 +123,7 @@ def _potrf_pipelined_fn(mesh, n: int, nb: int, d: int, dtype_str: str):
         return Lloc
 
     spec = P(None, _AXIS)
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=spec,
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=spec,
                                  out_specs=spec, check_vma=False))
 
 

@@ -41,7 +41,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..core.exceptions import slate_assert
 from .distribute import ceil_mult, lcm as _lcm
-from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS, shard_map
+from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS
 from ..obs import instrument
 
 
@@ -69,7 +69,7 @@ def _tsqr_dist_fn(mesh, dtype_str: str):
         return Q, R
 
     spec = P((ROW_AXIS, COL_AXIS), None)
-    fn = shard_map(local, mesh=mesh, in_specs=spec,
+    fn = jax.shard_map(local, mesh=mesh, in_specs=spec,
                        out_specs=(spec, P(None, None)), check_vma=False)
     return jax.jit(fn)
 
@@ -200,7 +200,7 @@ def _geqrf_dist_fn(mesh, mpad: int, npad: int, nb: int, dtype_str: str):
         return Q_loc, R_loc
 
     spec = P(ROW_AXIS, COL_AXIS)
-    fn = shard_map(local_fn, mesh=mesh, in_specs=spec,
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=spec,
                        out_specs=(spec, spec), check_vma=False)
     return jax.jit(fn)
 

@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..core.exceptions import slate_assert
 from .distribute import ceil_mult, lcm as _lcm
-from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS, shard_map
+from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS
 from ..obs import instrument
 
 
@@ -90,7 +90,7 @@ def _getrf_nopiv_dist_fn(mesh, npad: int, nb: int, dtype_str: str):
         return A_loc, _lu_diag_info(A_loc, grow, gcol, npad)
 
     spec = P(ROW_AXIS, COL_AXIS)
-    fn = shard_map(local_fn, mesh=mesh, in_specs=spec,
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=spec,
                        out_specs=(spec, P()), check_vma=False)
     return jax.jit(fn)
 

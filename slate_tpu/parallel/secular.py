@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS, shard_map
+from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS
 from ..obs import instrument
 
 _FLAT = (ROW_AXIS, COL_AXIS)
@@ -39,7 +39,7 @@ def _bisect_sharded_fn(mesh, m: int, m_pad: int, dtype_str: str):
 
     rep = P(None)
     shard = P(_FLAT)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(rep, rep, P(), shard, shard, shard, shard),
         out_specs=(shard, shard, shard),

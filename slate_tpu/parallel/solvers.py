@@ -32,7 +32,7 @@ from ..linalg.chol import _chol_blocked
 from ..ops import blas3
 from ..robust import RetryPolicy, Rung, guard_shards, inject, run_ladder
 from ..utils.trace import trace_event
-from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS, shard_map
+from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS
 from ..obs import instrument
 
 
@@ -293,7 +293,7 @@ def _trsmA_dist_fn(mesh, npad: int, nb: int, nrhs: int, lower: bool,
         X = lax.fori_loop(0, nt, body, jnp.zeros_like(b))
         return X
 
-    fn = shard_map(local_fn, mesh=mesh,
+    fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(P(_FLAT, None), P(None, None)),
                        out_specs=P(None, None), check_vma=False)
     return jax.jit(fn)
@@ -496,7 +496,7 @@ def _cholqr_fn(mesh, precision):
         bad = ~jnp.all(jnp.isfinite(jnp.diagonal(Rg)))
         return lax.cond(bad, householder_path, gram_path, None)
 
-    fn = shard_map(local, mesh=mesh, in_specs=in_spec,
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_spec,
                        out_specs=(in_spec, P(None, None)), check_vma=False)
     return jax.jit(fn)
 

@@ -31,7 +31,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.exceptions import slate_assert
-from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS, shard_map
+from .mesh import COL_AXIS, ProcessGrid, ROW_AXIS
 from .collectives import ring_shift
 from ..obs import instrument
 
@@ -44,7 +44,7 @@ def _allgather_fn(mesh, precision):
         b_full = lax.all_gather(b, ROW_AXIS, axis=0, tiled=True)
         return jnp.matmul(a_full, b_full, precision=precision)
 
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                        in_specs=(P(ROW_AXIS, COL_AXIS), P(ROW_AXIS, COL_AXIS)),
                        out_specs=P(ROW_AXIS, COL_AXIS))
     return jax.jit(fn)
@@ -91,7 +91,7 @@ def _ring_fn(mesh, p, q, precision):
         a, b, c = lax.fori_loop(0, steps - 1, body, (a, b, c))
         return c
 
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                        in_specs=(P(ROW_AXIS, COL_AXIS), P(ROW_AXIS, COL_AXIS)),
                        out_specs=P(ROW_AXIS, COL_AXIS))
     return jax.jit(fn)

@@ -44,12 +44,7 @@ import jax
 
 from . import lapack_api as _lapi
 
-try:
-    from .parallel import ProcessGrid, gemm_allgather
-    _HAVE_PARALLEL = True
-except Exception:  # pragma: no cover - environment-specific
-    ProcessGrid = None
-    _HAVE_PARALLEL = False
+from .parallel import ProcessGrid, gemm_allgather
 
 _grid: Optional["ProcessGrid"] = None
 
@@ -61,8 +56,6 @@ def gridinit(p: int, q: int) -> "ProcessGrid":
     (≅ Cblacs_gridinit; the reference reads the BLACS context off the
     descriptor, scalapack_api builds matrices on it)."""
     global _grid
-    if not _HAVE_PARALLEL:
-        raise RuntimeError("parallel layer unavailable; cannot build a grid")
     ndev = len(jax.devices())
     if p * q > ndev:
         raise ValueError(f"grid {p}x{q} needs {p*q} devices, have {ndev}")
@@ -521,7 +514,7 @@ def _make(letter, name, lapack_fn):
     def fn(*args, **kw):
         # distributed path on a real (>1 device) grid; single-device grids and
         # unsupported variants run the shared driver layer
-        if (_grid is not None and _HAVE_PARALLEL and _grid.p * _grid.q > 1
+        if (_grid is not None and _grid.p * _grid.q > 1
                 and name in _DISTRIBUTED
                 and _supports_distributed(name, args, kw)):
             return _DISTRIBUTED[name](_lapi._TYPES[letter], *args, **kw)

@@ -49,13 +49,13 @@ def _ref_time(routine: str, params: dict) -> Optional[float]:
 
 
 def x64_scope(dtypes: Sequence[str]):
-    """Scoped x64 for d/z sweeps: ``jax.experimental.enable_x64`` around the
+    """Scoped x64 for d/z sweeps: ``jax.enable_x64(True)`` around the
     sweep instead of the old process-global ``jax.config.update`` (which
     leaked x64 state across sweep rows and into library callers — the same
     scoped pattern testing/routines.py's gesv_mixed promotion uses)."""
     if any(t in ("d", "z") for t in dtypes):
-        from jax.experimental import enable_x64
-        return enable_x64()
+        import jax
+        return jax.enable_x64(True)
     import contextlib
     return contextlib.nullcontext()
 

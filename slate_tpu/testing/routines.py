@@ -368,11 +368,11 @@ def run_gesv_mixed(p, slate):
     promoted = {np.dtype(np.float32): np.float64,
                 np.dtype(np.complex64): np.complex128}.get(np.dtype(p["dtype"]))
     if promoted is not None:
-        # scoped x64 (jax.experimental.enable_x64) keeps the promotion local
-        # to this row — the rest of the sweep stays in the caller's mode
-        from jax.experimental import enable_x64
+        # scoped x64 (jax.enable_x64) keeps the promotion local to this row
+        # — the rest of the sweep stays in the caller's mode
+        import jax
 
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _gesv_mixed_body(dict(p, dtype=promoted), slate)
         out.setdefault("details", {})["promoted"] = \
             f"s/c -> {np.dtype(promoted).char}"

@@ -620,7 +620,7 @@ class TestCollectiveAuditCompiled:
         import jax.numpy as jnp
         from jax import lax
         from slate_tpu.parallel import ProcessGrid
-        from slate_tpu.parallel.mesh import COL_AXIS, ROW_AXIS, shard_map
+        from slate_tpu.parallel.mesh import COL_AXIS, ROW_AXIS
         from jax.sharding import PartitionSpec as P
 
         g = ProcessGrid(devices=jax.devices()[:2])
@@ -631,7 +631,7 @@ class TestCollectiveAuditCompiled:
             gathered = lax.all_gather(al, ax)
             return s + gathered.sum(axis=0)
 
-        fn = shard_map(local_fn, mesh=g.mesh, in_specs=P(ax, None),
+        fn = jax.shard_map(local_fn, mesh=g.mesh, in_specs=P(ax, None),
                        out_specs=P(ax, None))
         compiled = jax.jit(fn).lower(
             jnp.ones((8, 4), jnp.float32)).compile()

@@ -229,14 +229,39 @@ def test_gesv_mixed_f32_falls_back_cleanly(rng):
     assert resid < 1e-4
 
 
-def test_gemm_summa_without_distributed_layer_raises():
+def test_gemm_summa_matches_product():
     import slate_tpu as slate
     a = np.ones((4, 4))
-    try:
-        slate.gemm(1.0, a, a, 0.0, a.copy(), {"method_gemm": "summa"})
-    except slate.SlateError:
-        pass  # clear library error expected (if parallel layer absent)
-    # if the parallel layer exists, SUMMA must produce the right product
-    else:
-        got = slate.gemm(1.0, a, a, 0.0, np.zeros((4, 4)), {"method_gemm": "summa"})
-        np.testing.assert_allclose(np.asarray(got), a @ a)
+    got = slate.gemm(1.0, a, a, 0.0, np.zeros((4, 4)), {"method_gemm": "summa"})
+    np.testing.assert_allclose(np.asarray(got), a @ a)
+
+
+@pytest.mark.parametrize("m, kind, grid, want", [
+    (16384, "TPU v5 lite", False, "calu"),   # XLA's LU panel overflows VMEM
+    (8192, "TPU v5 lite", False, "partialpiv"),
+    (16384, "cpu", False, "partialpiv"),
+    (16384, "TPU v4", False, "partialpiv"),  # scope not measured there
+    (16384, "TPU v5 lite", True, "partialpiv"),  # grid-bound: distributed
+])
+def test_auto_lu_method_follows_vmem_fit(monkeypatch, m, kind, grid, want):
+    from types import SimpleNamespace
+
+    from slate_tpu.core.types import MethodLU
+
+    monkeypatch.setattr(lu_mod, "_operand_device",
+                        lambda a: SimpleNamespace(device_kind=kind))
+    a = jnp.zeros((m, 8), jnp.float32)
+    A = (slate.Matrix.from_array(a, p=2, q=1,
+                                 grid=slate.parallel.ProcessGrid(2, 1))
+         if grid else a)
+    assert lu_mod._auto_method(A) == {"calu": MethodLU.CALU,
+                                      "partialpiv": MethodLU.PartialPiv}[want]
+
+
+def test_operand_device_reads_concrete_and_traced_operands():
+    a = jnp.zeros((4, 4), jnp.float32)
+    assert lu_mod._operand_device(a) == next(iter(a.devices()))
+    assert lu_mod._operand_device(np.zeros((4, 4))) == jax.devices()[0]
+    seen = []
+    jax.jit(lambda x: seen.append(lu_mod._operand_device(x)) or x)(a)
+    assert seen == [jax.devices()[0]]

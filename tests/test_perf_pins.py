@@ -6,8 +6,8 @@ capture window is spent when a code change regresses the compiled shape of
 * the CALU panel schemes (flop counts vs the 2n^3/3 model; pp <= tournament),
 * the blocked Tiled potrf (the shipping bench path's flop envelope).
 
-Pins carry slack around the numbers measured at authoring time (recorded in
-BENCH_NOTES.md round 6) — they gate kernel SHAPE, not machine speed, so they
+Pins carry slack around the numbers measured at authoring time (round-6 bench
+notes, in git history) — they gate kernel SHAPE, not machine speed, so they
 hold on any backend.  All shapes compile in seconds on CPU.
 """
 
@@ -114,7 +114,7 @@ class TestLuPanelPins:
 
     def test_flat_panel_traffic_envelope(self):
         """The r5 regression mechanism was a ~3x bytes-accessed blowup from
-        the two-level split (BENCH_NOTES round 6).  The shipping flat-panel
+        the two-level split (round-6 bisection).  The shipping flat-panel
         config measured 2.53e7 bytes at this shape (24x the 1.05e6-byte
         array); gate at 1.6x the measured value so a traffic regression of
         the two-level kind fails before a capture is spent."""
@@ -128,7 +128,7 @@ class TestPotrfPins:
         0.96x of n^3/3 (the blocked-herk trailing update trims the square
         update's redundant half).  Gate at 1.1x — the lookahead pipeline
         compiles to ~2x this at the same job (the round-6 Tiled-vs-pipeline
-        decision evidence, BENCH_NOTES.md), so a default swap or a trailing-
+        decision evidence), so a default swap or a trailing-
         update regression fails here."""
         from slate_tpu.linalg.chol import _potrf_tiled_fn
 
