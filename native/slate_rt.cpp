@@ -3,12 +3,11 @@
 // Reference analogue: the C++ runtime layer of the reference —
 //   * include/slate/func.hh block-cyclic tile->rank lambdas and
 //     include/slate/internal/MatrixStorage.hh's tile directory,
-//   * src/core/Memory.cc fixed-block free-list pool (per-device tile allocator),
-//   * src/auxiliary/Trace.cc low-overhead event recording.
+//   * src/core/Memory.cc fixed-block free-list pool (per-device tile allocator).
 //
 // On TPU the device compute path is XLA/Pallas, but the *host* bookkeeping —
 // owner-map materialization over large tile grids, local-tile enumeration,
-// redistribution planning, workspace-pool accounting, trace event capture — is
+// redistribution planning, workspace-pool accounting — is
 // exactly the kind of integer-heavy, allocation-free work the reference keeps in
 // C++.  This library provides those pieces behind a plain C ABI consumed via
 // ctypes (slate_tpu/native.py), with pure-Python fallbacks when the shared
@@ -16,13 +15,8 @@
 //
 // Build: `make` in this directory (g++ -O3 -shared -fPIC).
 
-#include <atomic>
-#include <chrono>
 #include <cstdint>
-#include <cstdio>
-#include <cstring>
 #include <mutex>
-#include <string>
 #include <vector>
 
 extern "C" {
@@ -147,115 +141,5 @@ int64_t srt_pool_capacity(void* p) {
 }
 
 int64_t srt_pool_peak(void* p) { return static_cast<SrtPool*>(p)->peak; }
-
-// ---------------------------------------------------------------------------
-// trace event capture (Trace.cc: per-thread event vectors + one writer; here a
-// mutex-guarded vector + chrome://tracing JSON dump, the portable successor of
-// the reference's SVG timeline)
-
-struct SrtEvent {
-    std::string name;
-    double ts_us;     // event time
-    double dur_us;    // duration (complete events)
-    int32_t tid;
-};
-
-static std::vector<SrtEvent> g_events;
-static std::mutex g_trace_mu;
-static bool g_trace_on = false;
-static const auto g_t0 = std::chrono::steady_clock::now();
-
-// per-thread open-block stacks, matching Trace.cc's per-thread event vectors:
-// begin/end pairs from different threads must never cross
-static thread_local std::vector<SrtEvent> t_open;
-static std::atomic<int32_t> g_next_tid{0};
-static thread_local int32_t t_tid = -1;
-
-static int32_t my_tid() {
-    if (t_tid < 0) t_tid = g_next_tid.fetch_add(1);
-    return t_tid;
-}
-
-static double now_us() {
-    return std::chrono::duration<double, std::micro>(
-        std::chrono::steady_clock::now() - g_t0).count();
-}
-
-void srt_trace_enable(int32_t on) {
-    std::lock_guard<std::mutex> lock(g_trace_mu);
-    g_trace_on = on != 0;
-}
-
-void srt_trace_begin(const char* name) {
-    {
-        std::lock_guard<std::mutex> lock(g_trace_mu);
-        if (!g_trace_on) return;
-    }
-    t_open.push_back({name ? name : "", now_us(), 0.0, my_tid()});
-}
-
-void srt_trace_end() {
-    if (t_open.empty()) return;
-    SrtEvent ev = t_open.back();
-    t_open.pop_back();
-    ev.dur_us = now_us() - ev.ts_us;
-    std::lock_guard<std::mutex> lock(g_trace_mu);
-    if (g_trace_on) g_events.push_back(std::move(ev));
-}
-
-int64_t srt_trace_count() {
-    std::lock_guard<std::mutex> lock(g_trace_mu);
-    return static_cast<int64_t>(g_events.size());
-}
-
-void srt_trace_clear() {
-    std::lock_guard<std::mutex> lock(g_trace_mu);
-    g_events.clear();
-    t_open.clear();
-}
-
-// Minimal JSON string escaping (quotes, backslashes, control chars) so arbitrary
-// block names can't corrupt the dump.
-static std::string json_escape(const std::string& s) {
-    std::string out;
-    out.reserve(s.size() + 8);
-    for (unsigned char c : s) {
-        switch (c) {
-            case '"': out += "\\\""; break;
-            case '\\': out += "\\\\"; break;
-            case '\n': out += "\\n"; break;
-            case '\t': out += "\\t"; break;
-            case '\r': out += "\\r"; break;
-            default:
-                if (c < 0x20) {
-                    char buf[8];
-                    std::snprintf(buf, sizeof buf, "\\u%04x", c);
-                    out += buf;
-                } else {
-                    out += static_cast<char>(c);
-                }
-        }
-    }
-    return out;
-}
-
-// Chrome trace-event JSON ("X" complete events). Returns 0 on success.
-int32_t srt_trace_dump(const char* path) {
-    std::lock_guard<std::mutex> lock(g_trace_mu);
-    FILE* f = std::fopen(path, "w");
-    if (!f) return -1;
-    std::fputs("{\"traceEvents\":[", f);
-    for (size_t k = 0; k < g_events.size(); ++k) {
-        const auto& ev = g_events[k];
-        std::fprintf(f,
-            "%s{\"name\":\"%s\",\"ph\":\"X\",\"pid\":0,\"tid\":%d,"
-            "\"ts\":%.3f,\"dur\":%.3f}",
-            k ? "," : "", json_escape(ev.name).c_str(), ev.tid, ev.ts_us,
-            ev.dur_us);
-    }
-    std::fputs("]}", f);
-    std::fclose(f);
-    return 0;
-}
 
 }  // extern "C"

@@ -215,8 +215,11 @@ def potrf(A, opts=None, uplo=None):
     the_uplo = uplo or (A.uplo if isinstance(A, BaseMatrix) and A.uplo != Uplo.General
                         else Uplo.Lower)
     the_uplo = Uplo.from_string(the_uplo)
-    Af = _full_spd(A, the_uplo if not isinstance(A, (HermitianMatrix, SymmetricMatrix))
-                   else None)
+    # the phases' scopes name the compiled operations (potrf/prep, ...)
+    with trace_block("prep"):
+        Af = _full_spd(A, the_uplo if not isinstance(A, (HermitianMatrix,
+                                                         SymmetricMatrix))
+                       else None)
     Af = inject("potrf", Af)
     n = Af.shape[-1]
     target = opts.target
@@ -224,7 +227,7 @@ def potrf(A, opts=None, uplo=None):
         target = Target.XLA  # single fused factorization; Tiled for distributed runs
 
     grid = distribution_grid(A)
-    with trace_block("potrf", n=n, nb=opts.block_size, target=str(target)):
+    with trace_block("factor", n=n, nb=opts.block_size, target=str(target)):
         if grid is not None:
             # the wrapper carries a >1-device process grid: run the sharded
             # factorization over it (reference: distribution installed at
@@ -234,11 +237,16 @@ def potrf(A, opts=None, uplo=None):
             L = potrf_distributed(Af, grid, nb=min(opts.block_size, n),
                                   lookahead=opts.lookahead)
         elif target == Target.XLA:
-            L = jnp.tril(lax.linalg.cholesky(Af))
+            L = lax.linalg.cholesky(Af)
         else:
             L = _potrf_tiled_fn(n, min(opts.block_size, n), str(Af.dtype),
                                 inv_trsm=opts.trsm_via_inverse)(Af)
-    info = _chol_info(L)
+    if grid is None and target == Target.XLA:
+        # XLA's Cholesky leaves the strict upper triangle unspecified
+        with trace_block("mask"):
+            L = jnp.tril(L)
+    with trace_block("info"):
+        info = _chol_info(L)
     if opts.exact_info and int(info) != 0:
         # opt-in host refinement: XLA's Cholesky NaN-fills the whole factor, so
         # the exact first-failing-pivot index needs a host pass.  Off by
@@ -249,10 +257,12 @@ def potrf(A, opts=None, uplo=None):
     out = L if the_uplo == Uplo.Lower else jnp.conj(L.T)
     if isinstance(A, BaseMatrix):
         # store only into the stored triangle, leave the rest untouched
-        stored = as_array(A)
-        mask = jnp.tril(jnp.ones_like(stored, dtype=bool)) if the_uplo == Uplo.Lower \
-            else jnp.triu(jnp.ones_like(stored, dtype=bool))
-        write_back(A, jnp.where(mask, out, stored))
+        with trace_block("store"):
+            stored = as_array(A)
+            mask = jnp.tril(jnp.ones_like(stored, dtype=bool)) \
+                if the_uplo == Uplo.Lower \
+                else jnp.triu(jnp.ones_like(stored, dtype=bool))
+            write_back(A, jnp.where(mask, out, stored))
     return out, info
 
 
@@ -276,26 +286,34 @@ def potrs(A, B, opts=None, uplo=None):
     opts = Options.make(opts)
     the_uplo = Uplo.from_string(uplo or (A.uplo if isinstance(A, BaseMatrix)
                                          and A.uplo != Uplo.General else Uplo.Lower))
-    F = as_array(A)
-    L = jnp.tril(F) if the_uplo == Uplo.Lower else jnp.conj(jnp.triu(F).T)
-    b = as_array(B)
     grid = distribution_grid(A, B)
-    if grid is not None:
-        # grid-bound operands: the stationary-A sweeps (trsmA.cc; the factor
-        # never moves, nb x nrhs blocks of X travel).  XLA's own transposed
-        # TriangularSolve over a 2x2-sharded factor needs 17.6 GB per device
-        # at n=16384 (described-v5e compile), more than the chip holds.
-        from ..parallel.solvers import trsmA_distributed
+    span = {} if grid is None else {"grid": f"{grid.p}x{grid.q}"}
+    # the phases' scopes name the compiled operations (potrs/prep, ...)
+    with trace_block("potrs", **span):
+        with trace_block("prep"):
+            F = as_array(A)
+            L = jnp.tril(F) if the_uplo == Uplo.Lower else jnp.conj(jnp.triu(F).T)
+        b = as_array(B)
+        if grid is not None:
+            # grid-bound operands: the stationary-A sweeps (trsmA.cc; the
+            # factor never moves, nb x nrhs blocks of X travel).  XLA's own
+            # transposed TriangularSolve over a 2x2-sharded factor needs 17.6
+            # GB per device at n=16384 (described-v5e compile), more than the
+            # chip holds.
+            from ..parallel.solvers import trsmA_distributed
 
-        with trace_block("potrs", grid=f"{grid.p}x{grid.q}"):
-            y = trsmA_distributed(L, b, grid, lower=True)
-            x = trsmA_distributed(L, y, grid, lower=True, conj_trans=True)
-        return write_back(B, x)
-    with trace_block("potrs"):
-        y = lax.linalg.triangular_solve(L, b, left_side=True, lower=True)
-        x = lax.linalg.triangular_solve(L, y, left_side=True, lower=True,
-                                        conjugate_a=True, transpose_a=True)
-    return write_back(B, x)
+            with trace_block("forward"):
+                y = trsmA_distributed(L, b, grid, lower=True)
+            with trace_block("backward"):
+                x = trsmA_distributed(L, y, grid, lower=True, conj_trans=True)
+        else:
+            with trace_block("forward"):
+                y = lax.linalg.triangular_solve(L, b, left_side=True, lower=True)
+            with trace_block("backward"):
+                x = lax.linalg.triangular_solve(L, y, left_side=True, lower=True,
+                                                conjugate_a=True, transpose_a=True)
+        with trace_block("store"):
+            return write_back(B, x)
 
 
 @instrument

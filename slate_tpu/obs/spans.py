@@ -12,6 +12,10 @@ A *span* is a host-side named region that simultaneously
 
 Spans nest; a child records its parent's routine under the ``parent`` label
 so nested driver compositions (gesv -> getrf -> trsm) remain attributable.
+Under ``jax.jit`` a span runs once, while the call is traced: it then names the
+compiled operations (``trace_block`` is a ``jax.named_scope``), and its samples
+carry ``traced="true"``, so they count traces and never mix with the timings
+of eager calls.
 
 :func:`instrument` is the decorator the distributed drivers wear: it derives
 the standard labels (dtype + shape bucket from the first array argument,
@@ -29,6 +33,8 @@ import functools
 import threading
 import time
 from typing import Any, Dict, Optional
+
+import jax
 
 from ..utils.trace import trace_block
 from .registry import REGISTRY
@@ -167,6 +173,16 @@ def _derive_labels(args, kwargs) -> Dict[str, Any]:
     return labels
 
 
+def _traced(args, kwargs) -> bool:
+    """Whether an argument, or the array behind a Matrix wrapper argument, is
+    a ``jax.core.Tracer``: the call is being traced, not run."""
+    for a in (*args, *kwargs.values()):
+        a = getattr(getattr(a, "storage", None), "array", a)
+        if isinstance(a, jax.core.Tracer):
+            return True
+    return False
+
+
 def instrument(fn=None, *, routine: Optional[str] = None):
     """Decorator: wrap a driver in an observability scope.
 
@@ -185,7 +201,10 @@ def instrument(fn=None, *, routine: Optional[str] = None):
 
         @functools.wraps(f)
         def wrapper(*args, **kwargs):
-            with scope(name, **_derive_labels(args, kwargs)):
+            labels = _derive_labels(args, kwargs)
+            if _traced(args, kwargs):
+                labels["traced"] = "true"
+            with scope(name, **labels):
                 return f(*args, **kwargs)
 
         setattr(wrapper, INSTRUMENT_ATTR, name)

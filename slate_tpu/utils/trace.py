@@ -6,13 +6,16 @@ the per-driver ``timers[]`` phase map surfaced by the tester at --timer-level 2
 (src/heev.cc:126-212).
 
 TPU re-design: the device-side timeline comes for free from ``jax.profiler`` (XLA
-emits a perfetto trace), so this module provides the *host-side* named-region API:
+emits a perfetto trace), so this module names regions for it:
 
-- ``trace_block(name, **attrs)`` context manager ≅ ``trace::Block``; nests.
-- When enabled (``trace.on()``), events are recorded and can be dumped as a
+- ``trace_block(name, **attrs)`` context manager ≅ ``trace::Block``; nests.  It is
+  always a ``jax.named_scope`` (traced under ``jax.jit``, every operation inside
+  carries ``name`` in its HLO ``op_name``, so the profiler's device operations
+  can be attributed to it) and a ``jax.profiler.TraceAnnotation`` (run eagerly,
+  the region lands on the profiler's clock beside the device operations).
+- When enabled (``trace.on()``), events are also recorded and can be dumped as a
   chrome://tracing JSON (``trace.finish(path)``) — the portable successor of the
-  reference's SVG writer — and mirrored into ``jax.profiler.TraceAnnotation`` so host
-  regions line up with XLA device slices in one profile.
+  reference's SVG writer.
 - ``Timers`` accumulates named phase durations (the drivers' ``timers[]`` map).
 """
 
@@ -25,10 +28,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-try:  # TraceAnnotation shows host regions inside XLA profiles
-    from jax.profiler import TraceAnnotation as _JaxAnnotation
-except Exception:  # pragma: no cover
-    _JaxAnnotation = None
+import jax
 
 _state = threading.local()
 _enabled = False
@@ -38,28 +38,14 @@ _t0 = time.perf_counter()
 
 
 def on() -> None:
-    """Enable tracing (reference trace::Trace::on()).  Also arms the native
-    capture buffer (native/slate_rt.cpp) when the runtime library is built."""
+    """Enable tracing (reference trace::Trace::on())."""
     global _enabled
     _enabled = True
-    try:
-        from .. import native
-        native.trace_enable(True)
-    # slate-lint: disable=SLT501 -- optional native runtime: arming the C++
-    # capture buffer may fail in fallback-only environments; no solve runs
-    except Exception:  # pragma: no cover - fallback-only environments
-        pass
 
 
 def off() -> None:
     global _enabled
     _enabled = False
-    try:
-        from .. import native
-        native.trace_enable(False)    # disarm the C++ capture buffer too
-    # slate-lint: disable=SLT501 -- optional native runtime (see on())
-    except Exception:  # pragma: no cover
-        pass
 
 
 def is_on() -> bool:
@@ -158,42 +144,32 @@ def emit_span(name: str, t_start: float, t_end: float, **attrs) -> None:
 
 @contextlib.contextmanager
 def trace_block(name: str, **attrs):
-    """RAII-style named region (reference trace::Block, internal/Trace.hh:103-108)."""
-    if not _enabled:
-        if _JaxAnnotation is not None and os.environ.get("SLATE_TPU_JAX_TRACE"):
-            with _JaxAnnotation(name):
-                yield
-        else:
+    """RAII-style named region (reference trace::Block, internal/Trace.hh:103-108).
+
+    Opens ``jax.named_scope(name)``, which adds nothing to a compiled program
+    but the name in its operations' ``op_name`` metadata, and
+    ``jax.profiler.TraceAnnotation(name)``, a no-op without a profiler
+    session.  Under ``trace.on()`` the region is also recorded for
+    :func:`finish`."""
+    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
+        if not _enabled:
             yield
-        return
-    start = time.perf_counter()
-    try:
-        from .. import native as _nat
-        _nat.trace_begin(name)
-    # slate-lint: disable=SLT501 -- optional native runtime (see on());
-    # only the import/ctypes call can fail, the traced region runs outside
-    except Exception:  # pragma: no cover
-        _nat = None
-    try:
-        if _JaxAnnotation is not None:
-            with _JaxAnnotation(name):
-                yield
-        else:
+            return
+        start = time.perf_counter()
+        try:
             yield
-    finally:
-        if _nat is not None:
-            _nat.trace_end()
-        end = time.perf_counter()
-        ev = {
-            "name": name, "ph": "X", "cat": "slate",
-            "ts": (start - _t0) * 1e6, "dur": (end - start) * 1e6,
-            "pid": os.getpid(), "tid": threading.get_ident() % 2**31,
-        }
-        attrs = _stamp_request(attrs)
-        if attrs:
-            ev["args"] = {k: str(v) for k, v in attrs.items()}
-        with _events_lock:
-            _events.append(ev)
+        finally:
+            end = time.perf_counter()
+            ev = {
+                "name": name, "ph": "X", "cat": "slate",
+                "ts": (start - _t0) * 1e6, "dur": (end - start) * 1e6,
+                "pid": os.getpid(), "tid": threading.get_ident() % 2**31,
+            }
+            attrs = _stamp_request(attrs)
+            if attrs:
+                ev["args"] = {k: str(v) for k, v in attrs.items()}
+            with _events_lock:
+                _events.append(ev)
 
 
 def trace_event(name: str, **attrs) -> None:

@@ -1,11 +1,7 @@
 """Native runtime (native/slate_rt.cpp via ctypes) + Python fallback equivalence
 (≅ unit_test/test_Memory.cc, test_func.cc)."""
 
-import json
-import os
-
 import numpy as np
-import pytest
 
 import slate_tpu
 from slate_tpu import native
@@ -70,39 +66,6 @@ class TestMemoryPool:
         pool = native.MemoryPool(64, 2)
         assert not pool.free(99)
         assert not pool.free(-1)
-
-
-class TestNativeTrace:
-    def test_capture_and_dump(self, tmp_path):
-        if native.backend() != "native":
-            pytest.skip("native library not built")
-        native.trace_clear()
-        native.trace_enable(True)
-        native.trace_begin("outer")
-        native.trace_begin("inner")
-        native.trace_end()
-        native.trace_end()
-        native.trace_enable(False)
-        assert native.trace_count() == 2
-        path = str(tmp_path / "trace.json")
-        assert native.trace_dump(path)
-        events = json.load(open(path))["traceEvents"]
-        assert {e["name"] for e in events} == {"outer", "inner"}
-        assert all(e["ph"] == "X" and e["dur"] >= 0 for e in events)
-        native.trace_clear()
-
-    def test_trace_block_feeds_native(self, tmp_path):
-        if native.backend() != "native":
-            pytest.skip("native library not built")
-        from slate_tpu.utils import trace
-        native.trace_clear()
-        trace.on()
-        with trace.trace_block("native-hook"):
-            pass
-        trace.off()
-        native.trace_enable(False)
-        assert native.trace_count() >= 1
-        native.trace_clear()
 
 
 class TestMatrixIntegration:

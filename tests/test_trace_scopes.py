@@ -1,0 +1,202 @@
+"""Named regions inside compiled programs: ``trace_block`` is a
+``jax.named_scope``, so the operations a jitted solve compiles to carry the
+solve's phase names in their HLO ``op_name``; spans taken while a call is
+traced are labelled apart from eager calls."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import slate_tpu as slate
+from slate_tpu import obs
+from slate_tpu.utils import trace
+
+N, NRHS = 512, 16
+A_SPEC = jax.ShapeDtypeStruct((N, N), jnp.float32)
+B_SPEC = jax.ShapeDtypeStruct((N, NRHS), jnp.float32)
+PHASE = re.compile(r"(?:^|/)(potrf|potrs)/(prep|factor|mask|info|store|"
+                   r"forward|backward)(?=/|$)")
+COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$")
+KERNEL = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = .* (fusion|custom-call)\(")
+
+
+def compiled_text(fn, *specs) -> str:
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+def kernels(text):
+    """``{instruction: op_name or None}`` of the fusions and custom calls
+    that run as device operations (those outside fused computations)."""
+    called = set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", text))
+    out, comp = {}, None
+    for line in text.splitlines():
+        m = COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = KERNEL.match(line)
+        if m and comp not in called:
+            op = re.search(r'op_name="([^"]*)"', line)
+            out[m.group(1)] = op.group(1) if op else None
+    return out
+
+
+def phase(op_name):
+    """The innermost ``potrf/<phase>`` or ``potrs/<phase>`` of an op_name."""
+    found = PHASE.findall(op_name or "")
+    return "/".join(found[-1]) if found else None
+
+
+def check_scoped(text, expect):
+    """Every kernel traced from the program (its op_name starts ``jit(``;
+    the compiler's own relayouts of arguments carry the argument's name or
+    nothing) is inside a phase scope, and each kernel named in ``expect``
+    (``{(target, phase)}``) is there."""
+    ks = kernels(text)
+    traced = {k: v for k, v in ks.items() if v and v.startswith("jit(")}
+    assert traced
+    assert {k: v for k, v in traced.items() if phase(v) is None} == {}
+    found = set()
+    for line in text.splitlines():
+        m = re.search(r'custom_call_target="([^"]*)"', line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if m and op:
+            found.add((m.group(1), phase(op.group(1))))
+    assert expect <= found, found
+
+
+def public_programs():
+    def posv(a, b):
+        B = slate.Matrix.from_array(b)
+        _, info = slate.posv(
+            slate.HermitianMatrix.from_array(slate.Uplo.Lower, a), B)
+        return B.array, info
+
+    def potrf(a):
+        return slate.potrf(
+            slate.HermitianMatrix.from_array(slate.Uplo.Lower, a))
+
+    def potrs(l, b):
+        B = slate.Matrix.from_array(b)
+        slate.potrs(slate.HermitianMatrix.from_array(slate.Uplo.Lower, l), B)
+        return B.array
+
+    return posv, potrf, potrs
+
+
+def test_trace_block_names_the_compiled_operations():
+    def f(x):
+        with trace.trace_block("outer"):
+            with trace.trace_block("inner", n=3):
+                return jnp.sin(x) * 2.0
+
+    text = compiled_text(f, jax.ShapeDtypeStruct((8,), jnp.float32))
+    assert re.search(r'op_name="jit\(f\)/outer/inner/sin"', text)
+
+
+def test_trace_block_adds_nothing_but_metadata():
+    def plain(x):
+        return jnp.tril(x) @ x.T
+
+    def scoped(x):
+        with trace.trace_block("a"):
+            y = jnp.tril(x)
+        with trace.trace_block("b"):
+            return y @ x.T
+
+    def strip(text):
+        text = re.sub(r", metadata=\{[^}]*\}", "", text)
+        text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)"
+                      r"\n(?:[^\n]+\n)*", "\n", text)
+        return re.sub(r"HloModule \S+", "HloModule m", text)
+
+    spec = jax.ShapeDtypeStruct((16, 16), jnp.float32)
+    assert strip(compiled_text(plain, spec)) == strip(
+        compiled_text(scoped, spec))
+
+
+def test_posv_phases_cover_its_compiled_kernels():
+    posv, _, _ = public_programs()
+    check_scoped(compiled_text(posv, A_SPEC, B_SPEC), {
+        ("lapack_spotrf_ffi", "potrf/factor"),
+        ("lapack_strsm_ffi", "potrs/forward"),
+        ("lapack_strsm_ffi", "potrs/backward")})
+
+
+@pytest.mark.parametrize("routine", ["potrf", "potrs"])
+def test_factor_reuse_phases_cover_their_compiled_kernels(routine):
+    """potrf and potrs compiled apart, as a caller that reuses one factor
+    for many solves runs them."""
+    _, potrf, potrs = public_programs()
+    if routine == "potrf":
+        text = compiled_text(potrf, A_SPEC)
+        check_scoped(text, {("lapack_spotrf_ffi", "potrf/factor")})
+        kinds = {phase(v) for v in kernels(text).values() if v}
+        assert {"potrf/prep", "potrf/factor", "potrf/info"} <= kinds
+    else:
+        check_scoped(compiled_text(potrs, A_SPEC, B_SPEC), {
+            ("lapack_strsm_ffi", "potrs/forward"),
+            ("lapack_strsm_ffi", "potrs/backward")})
+
+
+class TestTracedSpans:
+    @pytest.fixture(autouse=True)
+    def _fresh_registry(self):
+        obs.reset()
+        yield
+        obs.reset()
+
+    @staticmethod
+    def samples(routine):
+        c = obs.REGISTRY.get("slate_spans_total")
+        return {key: v for key, v in c.series().items()
+                if dict(key).get("routine") == routine}
+
+    def test_spans_taken_while_tracing_are_labelled_traced(self):
+        posv, _, _ = public_programs()
+        a = np.eye(32, dtype=np.float32) * 2
+        b = np.ones((32, 2), np.float32)
+        f = jax.jit(posv)
+        for _ in range(3):
+            jax.block_until_ready(f(a, b))
+        spans = self.samples("posv")
+        # three calls, one trace: one sample, traced
+        assert list(spans.values()) == [1.0]
+        assert dict(next(iter(spans))).get("traced") == "true"
+        inner = self.samples("potrf")
+        assert list(inner.values()) == [1.0]
+        assert dict(next(iter(inner)))["parent"] == "posv"
+        assert dict(next(iter(inner))).get("traced") == "true"
+
+    def test_eager_calls_and_traces_never_share_a_series(self):
+        a = np.eye(32, dtype=np.float32) * 2
+        b = np.ones((32, 2), np.float32)
+        slate.posv(a, b.copy())
+        jax.jit(lambda a, b: slate.posv(a, b)[0])(a, b)
+        spans = self.samples("posv")
+        assert sorted(dict(k).get("traced", "-") for k in spans) == [
+            "-", "true"]
+        h = obs.REGISTRY.get("slate_span_seconds")
+        assert [h.snapshot(**dict(k))["count"] for k in spans] == [1, 1]
+
+
+def test_eager_trace_block_lands_on_the_profiler_clock(tmp_path):
+    """Run eagerly, a region is a profiler annotation with no ``trace.on()``,
+    on the profiler's host timeline beside the device operations."""
+    assert not trace.is_on()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.trace_block("eager_region"):
+            jax.block_until_ready(jnp.ones(4) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    from jax.profiler import ProfileData
+    import glob
+
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert "eager_region" in names
