@@ -67,6 +67,21 @@ def _full_spd(A, uplo) -> jax.Array:
     return (strict + other).at[..., idx, idx].set(diag)
 
 
+def _stored_lower(A, uplo: Uplo) -> jax.Array:
+    """The Hermitian matrix stored in the ``uplo`` triangle of ``A``, with its
+    lower triangle valid and its strict upper triangle left as it is: the
+    stored array itself for Lower, one conjugate transpose for Upper.  A
+    complex diagonal is real-cast (zpotrf ignores its imaginary part)."""
+    a = as_array(A)
+    if uplo == Uplo.Upper:
+        a = jnp.conj(jnp.swapaxes(a, -1, -2))
+    if jnp.iscomplexobj(a):
+        idx = jnp.arange(a.shape[-1])
+        diag = jnp.real(jnp.diagonal(a, axis1=-2, axis2=-1)).astype(a.dtype)
+        a = a.at[..., idx, idx].set(diag)
+    return a
+
+
 def _chol_info(L) -> jax.Array:
     """LAPACK-style info from a lower factor: 0 if SPD, else 1-based index of the
     first non-positive/NaN pivot — the shared info kernel
@@ -215,19 +230,30 @@ def potrf(A, opts=None, uplo=None):
     the_uplo = uplo or (A.uplo if isinstance(A, BaseMatrix) and A.uplo != Uplo.General
                         else Uplo.Lower)
     the_uplo = Uplo.from_string(the_uplo)
+    herm = isinstance(A, (HermitianMatrix, SymmetricMatrix))
+    src_uplo = A.uplo if herm else the_uplo
+    grid = distribution_grid(A)
+    # one chip factors the stored triangle where it lies (both factorizations
+    # read the lower half only); the sharded factorization, and a complex
+    # symmetric wrapper, which is not Hermitian, take the full matrix
+    full = grid is not None or (isinstance(A, SymmetricMatrix)
+                                and jnp.iscomplexobj(as_array(A)))
     # the phases' scopes name the compiled operations (potrf/prep, ...)
     with trace_block("prep"):
-        Af = _full_spd(A, the_uplo if not isinstance(A, (HermitianMatrix,
-                                                         SymmetricMatrix))
-                       else None)
+        if full:
+            Af = _full_spd(A, None if herm else the_uplo)
+        else:
+            Af = _stored_lower(A, src_uplo)
     Af = inject("potrf", Af)
     n = Af.shape[-1]
     target = opts.target
     if target == Target.Auto:
         target = Target.XLA  # single fused factorization; Tiled for distributed runs
 
-    grid = distribution_grid(A)
-    with trace_block("factor", n=n, nb=opts.block_size, target=str(target)):
+    source = "full" if full else ("stored_lower" if src_uplo == Uplo.Lower
+                                  else "transposed_upper")
+    with trace_block("factor", n=n, nb=opts.block_size, target=str(target),
+                     input=source):
         if grid is not None:
             # the wrapper carries a >1-device process grid: run the sharded
             # factorization over it (reference: distribution installed at
@@ -237,7 +263,7 @@ def potrf(A, opts=None, uplo=None):
             L = potrf_distributed(Af, grid, nb=min(opts.block_size, n),
                                   lookahead=opts.lookahead)
         elif target == Target.XLA:
-            L = lax.linalg.cholesky(Af)
+            L = lax.linalg.cholesky(Af, symmetrize_input=full)
         else:
             L = _potrf_tiled_fn(n, min(opts.block_size, n), str(Af.dtype),
                                 inv_trsm=opts.trsm_via_inverse)(Af)
@@ -254,10 +280,10 @@ def potrf(A, opts=None, uplo=None):
         # hazard), and potrf stays fully jittable without it.
         info = jnp.int32(_host_chol_info(Af))
 
-    out = L if the_uplo == Uplo.Lower else jnp.conj(L.T)
-    if isinstance(A, BaseMatrix):
-        # store only into the stored triangle, leave the rest untouched
-        with trace_block("store"):
+    with trace_block("store"):
+        out = L if the_uplo == Uplo.Lower else jnp.conj(L.T)
+        if isinstance(A, BaseMatrix):
+            # store only into the stored triangle, leave the rest untouched
             stored = as_array(A)
             mask = jnp.tril(jnp.ones_like(stored, dtype=bool)) \
                 if the_uplo == Uplo.Lower \

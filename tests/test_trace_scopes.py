@@ -68,7 +68,7 @@ def check_scoped(text, expect):
     assert expect <= found, found
 
 
-def public_programs():
+def public_programs(uplo=slate.Uplo.Lower):
     def posv(a, b):
         B = slate.Matrix.from_array(b)
         _, info = slate.posv(
@@ -76,8 +76,7 @@ def public_programs():
         return B.array, info
 
     def potrf(a):
-        return slate.potrf(
-            slate.HermitianMatrix.from_array(slate.Uplo.Lower, a))
+        return slate.potrf(slate.HermitianMatrix.from_array(uplo, a))
 
     def potrs(l, b):
         B = slate.Matrix.from_array(b)
@@ -135,11 +134,30 @@ def test_factor_reuse_phases_cover_their_compiled_kernels(routine):
         text = compiled_text(potrf, A_SPEC)
         check_scoped(text, {("lapack_spotrf_ffi", "potrf/factor")})
         kinds = {phase(v) for v in kernels(text).values() if v}
-        assert {"potrf/prep", "potrf/factor", "potrf/info"} <= kinds
+        assert {"potrf/factor", "potrf/info"} <= kinds
     else:
         check_scoped(compiled_text(potrs, A_SPEC, B_SPEC), {
             ("lapack_strsm_ffi", "potrs/forward"),
             ("lapack_strsm_ffi", "potrs/backward")})
+
+
+@pytest.mark.parametrize("uplo", ["lower", "upper"])
+def test_potrf_prep_is_the_upper_triangles_transpose_alone(uplo):
+    """A lower-stored factor reads the stored array as it is: ``potrf/prep``
+    compiles to nothing.  An upper-stored one is transposed once there."""
+    _, potrf, _ = public_programs(slate.Uplo.from_string(uplo))
+    text = compiled_text(potrf, A_SPEC)
+    check_scoped(text, {("lapack_spotrf_ffi", "potrf/factor")})
+    prep_kernels = [k for k, v in kernels(text).items()
+                    if phase(v) == "potrf/prep"]
+    prep_ops = re.findall(r'= \S+ ([\w\-]+)\(.*op_name="[^"]*potrf/prep/',
+                          text)
+    if uplo == "lower":
+        assert prep_kernels == [] and prep_ops == []
+    else:
+        assert len(prep_kernels) == 1
+        assert prep_ops.count("transpose") == 1
+        assert set(prep_ops) <= {"transpose", "copy", "fusion"}, prep_ops
 
 
 class TestTracedSpans:
