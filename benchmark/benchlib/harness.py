@@ -3,9 +3,12 @@
 Everything that belongs to one configuration, traffic mix or metric is
 found by its name:
 
-* ``configs/<config>.json`` (sizes, source, limits) and
-  ``configs/<config>.py`` (its ``System``: how the window drives the
-  system under test, and the check of what it produced);
+* ``configs/<config>.json`` (sizes, source, limits; its ``rehearsal``
+  sizes and recorded trace for the CPU self-tests; its ``scopes``: each
+  driver's phases with their roles, and the job each role is measured
+  against) and ``configs/<config>.py`` (its ``System``: how the window
+  drives the system under test, the check of what it produced, the
+  compiled texts of its programs and the faults the self-tests plant);
 * ``traffic/<traffic>.json``, the mix's parameters, read by the loop it
   names under ``"loop"``: ``loops/<loop>.py``, whose ``run`` drives the
   window;
@@ -198,7 +201,10 @@ def verdict(compared: dict, ok: bool, obs: dict) -> bool:
 def configure_jax(root: str):
     """JAX's persistent compilation cache where ``JAX_COMPILATION_CACHE_DIR``
     names one, else at the checkout's fixed ``.jax_cache``; every program is
-    written to it however short its compile."""
+    written to it however short its compile.  The cache's key covers the
+    programs' metadata: an executable cached by a tree whose scopes differ
+    would otherwise carry that tree's ``op_name``s into the trace's
+    reduction."""
     import jax
 
     jax.config.update("jax_compilation_cache_dir",
@@ -207,6 +213,21 @@ def configure_jax(root: str):
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     jax.config.update("jax_compilation_cache_max_size", -1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
+
+def scope_readings(red: dict, steps: int, vocab: dict, system,
+                   peaks: dict) -> dict:
+    """``scopes.readings`` of a traced window, each role measured against
+    the least time of its job (``vocab["jobs"]``) at the cell's sizes."""
+    from .jobs import least_time_s
+    from .scopes import readings
+
+    least = {}
+    for role, routine in vocab["jobs"].items():
+        job = system.job(routine)
+        least[role] = least_time_s(job["flops"], job["bytes"], peaks)[0]
+    return readings(red, steps, vocab["drivers"], least)
 
 
 def run_cell(root: str, workload: str, seed: int, seconds: float,
@@ -248,11 +269,16 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         in_window = counter.between(obs["t0"], obs["t_end"])
         split["rest"] = obs["t0"] - t_process - sum(split.values())
         setup_s = obs["t0"] - t_process
-        reduced = None
+        reduced = scoped = None
         if trace:
+            from . import scopes
             from .trace import reduce_trace
 
             reduced = reduce_trace(trace_dir)
+            t_red = time.perf_counter()
+            scoped = scopes.reduce_scopes(trace_dir, system.program_texts(),
+                                          cell.config["scopes"]["drivers"])
+            scoped["reduce_s"] = time.perf_counter() - t_red
             shutil.rmtree(trace_dir, ignore_errors=True)
         # the check runs once the window has closed and the peak is read
         t_chk = time.perf_counter()
@@ -262,8 +288,12 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         system.close()
     correct = verdict(compared, ok, obs)
     ctx = {"setup_s": setup_s, "obs": obs, "counters": hooks.counters,
-           "trace": reduced, "peaks": peaks, "job": system.job(),
-           "window_s": obs["window_s"]}
+           "trace": reduced, "scopes": None, "peaks": peaks,
+           "job": system.job(), "window_s": obs["window_s"]}
+    steps = (hooks.counters.get("end") or {}).get("done")
+    if scoped is not None and steps:
+        ctx["scopes"] = scope_readings(scoped, steps, cell.config["scopes"],
+                                       system, peaks)
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in cell.metrics(kind):
@@ -289,6 +319,13 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
                                   "errors": obs.get("errors", []),
                                   "check_s": check_s, **in_window}),
           file=log)
+    if scoped is not None:
+        from .scopes import breakdown
+
+        print("SCOPES " + json.dumps({
+            **breakdown(scoped), "readings": ctx["scopes"],
+            **{k: scoped[k] for k in ("in_program_s", "between_programs_s",
+                                      "reduce_s")}}), file=log)
     for name, v in compared.items():
         print(f"CHECK {name} {v['value']!r} limit {v['limit']!r}", file=log)
     log.flush()

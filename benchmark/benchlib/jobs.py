@@ -1,11 +1,13 @@
 """LAPACK job models: the operations and bytes a routine needs, from its
 shapes alone, whatever implements it.
 
-Flop counts follow LAPACK++ ``flops.hh`` (real arithmetic, leading and
-lower-order terms as there).  Bytes are the least traffic to and from
-device memory the routine needs, in the routine's own dtype: each operand
-read once and each result written once, except where the routine must
-sweep an operand more than once (potrs reads its factor in two sweeps).
+Flop counts follow LAPACK++ ``flops.hh`` (real arithmetic: its ``fmuls``
+plus its ``fadds``, leading and lower-order terms as there).  Bytes are the
+least traffic to and from device memory the routine needs, in the routine's
+own dtype (pivots as int32): each operand read once and each result written
+once, except where the routine must sweep an operand more than once (potrs
+reads its factor's triangle in each of its two sweeps; getrs reads the unit
+lower triangle of its packed factor in one and the upper in the other).
 """
 
 from __future__ import annotations
@@ -20,9 +22,8 @@ def potrs_flops(n: int, nrhs: int) -> float:
 
 
 def getrf_flops(m: int, n: int) -> float:
-    k = min(m, n)
-    return m * n * k - (m + n) * k ** 2 / 2.0 + k ** 3 / 3.0 \
-        + (m * n - k ** 2 / 2.0) / 2.0 + k / 6.0
+    k, big = min(m, n), max(m, n)
+    return big * k ** 2 - k ** 3 / 3.0 - k ** 2 / 2.0 + 5.0 * k / 6.0
 
 
 def getrs_flops(n: int, nrhs: int) -> float:
@@ -51,6 +52,14 @@ def job(routine: str, n: int, nrhs: int = 1, itemsize: int = 4,
     if routine == "potrs":
         # read L's triangle once per sweep
         return {"flops": potrs_flops(n, nrhs), "bytes": 2 * tri + rhs}
+    if routine == "getrf":
+        # read A, write L\U and the pivots
+        return {"flops": getrf_flops(m, n),
+                "bytes": 2.0 * m * n * itemsize + 4.0 * min(m, n)}
+    if routine == "getrs":
+        # the factor's two triangles (one per sweep) and the pivots read
+        return {"flops": getrs_flops(n, nrhs),
+                "bytes": n * n * itemsize + 4.0 * n + rhs}
     if routine == "gesv":
         return {"flops": getrf_flops(n, n) + getrs_flops(n, nrhs),
                 "bytes": 2.0 * n * n * itemsize + rhs}

@@ -10,7 +10,9 @@ programs.
   under, such as ``jit(posv)/posv/potrf/factor/cholesky``.  Instruction
   names are unique within a module.
 * An instruction's scope is the innermost ``<driver>/<phase>`` on that path
-  for the drivers and phases below (``potrf/factor``).  One without a scope
+  among the configuration's drivers and their phases (``potrf/factor``;
+  ``drivers`` maps each driver to ``{phase: role}``, see :func:`readings`
+  and ``configs/<config>.json``'s ``scopes``).  One without a scope
   of its own (an operation a compiler pass made, a relayout of an
   argument) takes the most common scope of its operands, else of its users,
   else that of the instruction that calls its computation (a loop body's
@@ -34,10 +36,6 @@ from .trace import (DEVICE_PLANE, OPS_LINE, find_xplane, host_timeline,
                     idle_gaps, union_length)
 
 MODULES_LINE = "XLA Modules"
-DRIVERS = ("potrf", "potrs")
-PHASES = ("prep", "factor", "mask", "info", "store", "forward", "backward")
-#: the phases that only move or mask data around the factor and the sweeps
-WRAPPER_PHASES = ("prep", "mask", "store")
 UNSCOPED = "(unscoped)"
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$")
@@ -48,11 +46,12 @@ _MODULE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
 _EVENT = re.compile(r"^%?([\w.\-]+) = ")
 
 
-def scope_of(op_name: str):
-    """The innermost ``<driver>/<phase>`` on an ``op_name`` path, or None."""
+def scope_of(op_name: str, drivers: dict):
+    """The innermost ``<driver>/<phase>`` on an ``op_name`` path whose
+    driver and phase ``drivers`` names, or None."""
     parts = op_name.split("/")
     for i in range(len(parts) - 2, -1, -1):
-        if parts[i] in DRIVERS and parts[i + 1] in PHASES:
+        if parts[i + 1] in drivers.get(parts[i], ()):
             return f"{parts[i]}/{parts[i + 1]}"
     return None
 
@@ -61,7 +60,7 @@ def module_name(text: str) -> str:
     return _MODULE.search(text).group(1)
 
 
-def instruction_scopes(text: str) -> dict:
+def instruction_scopes(text: str, drivers: dict) -> dict:
     """``{instruction: scope or None}`` for every instruction of one
     module's HLO text."""
     own, caller = {}, {}
@@ -80,7 +79,7 @@ def instruction_scopes(text: str) -> dict:
     for comp, name, line in lines:
         home[name] = comp
         op = _OP_NAME.search(line)
-        own[name] = scope_of(op.group(1)) if op else None
+        own[name] = scope_of(op.group(1), drivers) if op else None
         refs = _REF.findall(line[line.index("=") + 1:])
         operands[name] = [r for r in refs if r in home and home[r] == comp]
         for ref in refs:
@@ -116,9 +115,11 @@ def instruction_scopes(text: str) -> dict:
     return out
 
 
-def reduce_scopes(path: str, texts, window_name: str = "bench.window") -> dict:
+def reduce_scopes(path: str, texts, drivers: dict,
+                  window_name: str = "bench.window") -> dict:
     """Reduce the trace at ``path`` (a file or a profiler log directory)
-    against the HLO ``texts`` of the programs the window runs.
+    against the HLO ``texts`` of the programs the window runs, by the
+    scopes of ``drivers``.
 
     Returns ``busy_s``, ``window_s``, ``groups`` (``{group: seconds}``, see
     the module's doc), ``in_program_s`` (idle time inside a program's
@@ -131,7 +132,7 @@ def reduce_scopes(path: str, texts, window_name: str = "bench.window") -> dict:
         path = find_xplane(path)
     scopes = {}
     for t in texts:
-        scopes[module_name(t)] = instruction_scopes(t)
+        scopes[module_name(t)] = instruction_scopes(t, drivers)
     pd = ProfileData.from_file(path)
     ops, modules, window = [], [], None
     device_seen = False
@@ -225,35 +226,38 @@ def breakdown(red: dict, top: int = 10) -> dict:
             "programs": red["programs"][:top]}
 
 
-def phase_seconds(red: dict, phases) -> float:
-    """Device seconds of the scope groups whose phase is in ``phases``."""
-    return sum(s for g, s in red["groups"].items()
-               if "/" in g and g.split("/", 1)[1] in phases)
+def role_seconds(red: dict, drivers: dict) -> dict:
+    """Device seconds of each role: the sum of its scope groups."""
+    out = defaultdict(float)
+    for g, s in red["groups"].items():
+        driver, _, phase = g.partition("/")
+        role = drivers.get(driver, {}).get(phase)
+        if role:
+            out[role] += s
+    return out
 
 
-def readings(red: dict, steps: int, least_s: dict) -> dict:
-    """The per-phase numbers of a traced window of ``steps`` solves.
+def readings(red: dict, steps: int, drivers: dict, least_s: dict) -> dict:
+    """The per-role numbers of a traced window of ``steps`` solves.
 
-    ``least_s`` maps ``"potrf"`` and ``"potrs"`` to the job model's least
-    time of one call (``jobs.least_time_s``), or to None where the window
-    runs no such phase.  A number whose phase took no device time is left
-    out:
+    ``drivers`` gives each ``<driver>/<phase>`` a role (``wrapper``,
+    ``factor``, ``sweep``, ...) or none; ``least_s`` maps a role to the
+    least time of the job it is measured against, once per step
+    (``jobs.least_time_s``).  A roofline whose role took no device time is
+    left out:
 
-    * ``wrapper_share``: device time of the prep, mask and store phases
-      over busy time, in percent;
-    * ``factor_roofline``: the potrf job's least time over factor time per
-      solve, in percent;
-    * ``sweep_roofline``: the potrs job's least time over forward and
-      backward time per solve, in percent;
-    * ``host_gap_ms``: idle time between programs per solve, in ms."""
+    * ``<role>_share``: the role's device time over busy time, in percent,
+      for every role ``drivers`` names;
+    * ``<role>_roofline``: the least time over the role's device time per
+      step, in percent, for every role of ``least_s``;
+    * ``host_gap_ms``: idle time between programs per step, in ms."""
     out = {"host_gap_ms": red["between_programs_s"] * 1e3 / steps}
+    t = role_seconds(red, drivers)
     if red["busy_s"] > 0:
-        out["wrapper_share"] = 100.0 * phase_seconds(
-            red, WRAPPER_PHASES) / red["busy_s"]
-    for name, job, phases in (("factor_roofline", "potrf", ("factor",)),
-                              ("sweep_roofline", "potrs",
-                               ("forward", "backward"))):
-        t = phase_seconds(red, phases)
-        if least_s.get(job) and t > 0:
-            out[name] = 100.0 * least_s[job] / (t / steps)
+        for role in dict.fromkeys(r for phases in drivers.values()
+                                  for r in phases.values() if r):
+            out[f"{role}_share"] = 100.0 * t[role] / red["busy_s"]
+    for role, least in least_s.items():
+        if least and t[role] > 0:
+            out[f"{role}_roofline"] = 100.0 * least / (t[role] / steps)
     return out

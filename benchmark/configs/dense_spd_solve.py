@@ -142,9 +142,40 @@ class System:
     def counters(self):
         return {}
 
-    def job(self):
-        return _jobs().job(self.routine, self.n, self.nrhs,
+    def job(self, routine: str | None = None):
+        """The job model of one call of ``routine``, by default of the
+        traffic's routine, at this cell's sizes."""
+        return _jobs().job(routine or self.routine, self.n, self.nrhs,
                            itemsize=np.dtype(self.config["dtype"]).itemsize)
+
+    def program_texts(self):
+        """The compiled HLO text of every program loaded for the window."""
+        return [exe.as_text() for exe in self.exe.values()]
+
+    @staticmethod
+    def plant_fault(kind: str, monkeypatch):
+        """Break slate's potrs, which both routines' programs call, where it
+        produces the answer: ``altered`` (one entry), ``unchanged`` (the
+        right-hand sides returned as the answer) or ``half`` (half the
+        right-hand sides' answers left out)."""
+        import slate_tpu
+        from slate_tpu.core.matrix import as_array, write_back
+        from slate_tpu.linalg import chol
+
+        real = chol.potrs
+
+        def potrs(A, B, opts=None, uplo=None):
+            if kind == "unchanged":
+                return write_back(B, as_array(B))
+            x = real(A, B, opts, uplo)
+            if kind == "altered":
+                x = x.at[0, 0].add(1.0)
+            else:
+                x = x.at[:, ::2].set(0.0)
+            return write_back(B, x)
+
+        monkeypatch.setattr(chol, "potrs", potrs)
+        monkeypatch.setattr(slate_tpu, "potrs", potrs)
 
     # -- the check ------------------------------------------------------------
 

@@ -75,7 +75,11 @@ def main(prefix: str) -> int:
     shutil.rmtree(tmp)
     with open(prefix + ".hlo.txt", "w") as f:
         f.write(without_source_tables(exe.as_text()))
-    print(json.dumps(reduce_scopes(prefix + ".xplane.pb", [exe.as_text()])))
+    with open(os.path.join(os.path.dirname(HERE), "configs",
+                           "dense_spd_solve.json")) as f:
+        drivers = json.load(f)["scopes"]["drivers"]
+    print(json.dumps(reduce_scopes(prefix + ".xplane.pb", [exe.as_text()],
+                                   drivers)))
     return 0
 
 

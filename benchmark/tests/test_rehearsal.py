@@ -1,7 +1,8 @@
-"""Every cell, end to end at a tiny size on the CPU: the rehearsal path the
-chip path never takes (the command line refuses a run without a TPU).
-Also the faults the check must catch, planted in the system under test
-where it produces its answers, and the refusals."""
+"""Every cell, end to end at its configuration's rehearsal size on the CPU:
+the rehearsal path the chip path never takes (the command line refuses a
+run without a TPU).  Also the faults the check must catch, planted by the
+configuration where the system under test produces its answers, and the
+refusals."""
 
 import json
 import os
@@ -12,25 +13,37 @@ import time
 
 import pytest
 
-from conftest import BENCH, ROOT
+from conftest import BENCH, CELLS, DATA, ROOT
+from benchlib import jobs
 from benchlib.harness import Cell, run_cell
 
 PEAKS = {"flops_per_s": 1e12, "bytes_per_s": 1e11}
-# the limits of `correct` hold the f32 solve at n=16384; at n=2048 it
-# still reads under them, at n of a few hundred it does not
-DENSE = {"n": 2048, "nrhs": 4}
-SIZES = {"posv_n16384": DENSE, "potrs_n16384": DENSE}
-CELLS = sorted(SIZES)
+FAULTS = ("altered", "unchanged", "half")
 
 
 def rehearse(cell, trace=False, seed=2 ** 31 + 12345):
+    sizes = Cell(ROOT, cell).config["rehearsal"]["sizes"]
     return run_cell(ROOT, cell, seed, 0.5, trace, time.perf_counter(),
-                    allow_cpu=True, sizes=SIZES[cell], peaks_override=PEAKS)
+                    allow_cpu=True, sizes=sizes, peaks_override=PEAKS)
 
 
-def test_every_cell_is_rehearsed():
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    assert sorted(w["name"] for w in bench["workloads"]) == CELLS
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cells_configuration_declares_its_contract(cell):
+    """Rehearsal sizes and a recorded chip trace, the faults, the compiled
+    texts and a scope vocabulary whose roles' jobs are modelled."""
+    c = Cell(ROOT, cell)
+    rehearsal = c.config["rehearsal"]
+    assert rehearsal["sizes"]
+    for name in (rehearsal["trace"]["xplane"], rehearsal["trace"]["hlo"]):
+        assert os.path.isfile(os.path.join(DATA, name))
+    system = c.system_class()
+    assert callable(system.plant_fault) and callable(system.program_texts)
+    vocab = c.config["scopes"]
+    roles = {r for phases in vocab["drivers"].values()
+             for r in phases.values() if r}
+    assert roles and set(vocab["jobs"]) <= roles
+    for routine in vocab["jobs"].values():
+        assert jobs.job(routine, 64, 2)["flops"] > 0
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -47,60 +60,62 @@ def test_cell_runs_and_is_correct(cell):
         assert 0 <= v["value"] <= v["limit"]
 
 
-@pytest.fixture
-def chip_trace(monkeypatch):
-    """The traced run's reduction, from the recorded chip trace (a CPU
-    trace has no device plane)."""
-    from benchlib import trace
+def use_recorded_trace(cell, monkeypatch):
+    """The traced run's reductions, from the configuration's recorded chip
+    trace and the compiled text it ran (a CPU trace has no device plane),
+    by the configuration's own scope vocabulary."""
+    from benchlib import scopes, trace
 
-    data = os.path.join(os.path.dirname(__file__), "data",
-                        "v5e_small.xplane.pb")
-    if not os.path.exists(data):
-        pytest.skip("no recorded trace")
-    real = trace.reduce_trace(data)
+    rec = Cell(ROOT, cell).config["rehearsal"]["trace"]
+    xplane = os.path.join(DATA, rec["xplane"])
+    with open(os.path.join(DATA, rec["hlo"])) as f:
+        text = f.read()
+    real = trace.reduce_trace(xplane)
+    reduce_scopes = scopes.reduce_scopes
     monkeypatch.setattr(trace, "reduce_trace", lambda path, **kw: real)
+    monkeypatch.setattr(
+        scopes, "reduce_scopes",
+        lambda path, texts, drivers, **kw: reduce_scopes(xplane, [text],
+                                                         drivers, **kw))
     return real
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_traced_run_reports_per_layer_metrics(cell, chip_trace):
+def test_traced_run_reports_per_layer_metrics(cell, monkeypatch, capsys):
+    recorded = use_recorded_trace(cell, monkeypatch)
     r = rehearse(cell, trace=True)
     assert r["correct"] is True
     want = {m["name"] for m in Cell(ROOT, cell).metrics("per_layer")}
     assert set(r["metrics"]) == want
-    assert r["device"]["busy_s"] == chip_trace["busy_s"]
+    assert r["device"]["busy_s"] == recorded["busy_s"]
     assert set(r["breakdown"]) == {"device_ops", "idle_gaps"}
     assert not os.path.exists(os.path.join(ROOT, ".bench_trace", cell))
+    # the scope groups beside SETUP and WINDOW; the numbers compared last
+    err = capsys.readouterr().err.splitlines()
+    scoped = [json.loads(ln[len("SCOPES "):]) for ln in err
+              if ln.startswith("SCOPES ")]
+    assert len(scoped) == 1 and scoped[0]["scopes"]
+    assert all(ln.startswith("CHECK ") for ln in err[-len(r["compared"]):])
+
+
+def test_an_untraced_run_reduces_no_scopes(monkeypatch, capsys):
+    from benchlib import scopes
+
+    def refuse(*a, **kw):
+        raise AssertionError("an untraced run reduced scopes")
+
+    monkeypatch.setattr(scopes, "reduce_scopes", refuse)
+    r = rehearse(CELLS[0])
+    assert r["correct"] is True and "breakdown" not in r
+    assert "SCOPES " not in capsys.readouterr().err
 
 
 # -- faults planted where the answers are produced ---------------------------
 
-def plant_dense_fault(kind, monkeypatch):
-    """slate's potrs, which both dense cells' programs call, broken."""
-    import slate_tpu
-    from slate_tpu.core.matrix import as_array, write_back
-    from slate_tpu.linalg import chol
-
-    real = chol.potrs
-
-    def potrs(A, B, opts=None, uplo=None):
-        if kind == "unchanged":           # returns its input as the answer
-            return write_back(B, as_array(B))
-        x = real(A, B, opts, uplo)
-        if kind == "altered":
-            x = x.at[0, 0].add(1.0)
-        else:                             # half the right-hand sides left out
-            x = x.at[:, ::2].set(0.0)
-        return write_back(B, x)
-
-    monkeypatch.setattr(chol, "potrs", potrs)
-    monkeypatch.setattr(slate_tpu, "potrs", potrs)
-
-
-@pytest.mark.parametrize("kind", ["altered", "unchanged", "half"])
+@pytest.mark.parametrize("kind", FAULTS)
 @pytest.mark.parametrize("cell", CELLS)
 def test_a_planted_fault_reads_not_correct(cell, kind, monkeypatch):
-    plant_dense_fault(kind, monkeypatch)
+    Cell(ROOT, cell).system_class().plant_fault(kind, monkeypatch)
     r = rehearse(cell)
     assert r["correct"] is False
     assert any(v["value"] > v["limit"] for v in r["compared"].values())
@@ -120,6 +135,8 @@ def test_compile_cache_follows_the_environment(monkeypatch, tmp_path):
         configure_jax(ROOT)
         assert jax.config.jax_compilation_cache_dir == os.path.join(
             ROOT, ".jax_cache")
+        # an executable cached under other scopes is not taken for this one
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
     finally:
         jax.config.update("jax_compilation_cache_dir", old)
 

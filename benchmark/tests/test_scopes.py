@@ -3,11 +3,14 @@ time inside and between programs; on synthetic planes, on the small chip
 trace of ``record_trace.py`` and on the scoped one of
 ``record_scoped_trace.py``."""
 
+import json
 import os
 import types
 
 import pytest
 
+from conftest import BENCH
+from benchlib.jobs import job, least_time_s
 from benchlib.scopes import (UNSCOPED, breakdown, instruction_scopes,
                              readings, reduce_scopes, scope_of)
 from benchlib.trace import reduce_trace
@@ -15,6 +18,13 @@ from benchlib.trace import reduce_trace
 DATA = os.path.join(os.path.dirname(__file__), "data")
 SMALL = os.path.join(DATA, "v5e_small.xplane.pb")
 SCOPED = os.path.join(DATA, "v5e_scoped")
+with open(os.path.join(BENCH, "configs", "dense_spd_solve.json")) as _f:
+    DENSE = json.load(_f)["scopes"]
+DRIVERS = DENSE["drivers"]
+LU = {"getrf": {"select": "factor", "swap": "factor", "panel": "factor",
+                "update": "factor", "info": None},
+      "getrs": {"permute": "wrapper", "forward": "sweep",
+                "backward": "sweep", "store": "wrapper"}}
 
 HLO = """HloModule jit_f, entry_computation_layout={(f32[4]{0})->f32[4]{0}}
 
@@ -51,21 +61,27 @@ ENTRY %main.1 (a: f32[4]) -> f32[4] {
 """
 
 
-@pytest.mark.parametrize("op_name, scope", [
-    ("jit(posv)/posv/potrf/factor/cholesky", "potrf/factor"),
-    ("jit(posv)/posv/potrf/potrf/factor/cholesky", "potrf/factor"),
-    ("jit(posv)/posv/potrs/prep/jit(tril)/select_n", "potrs/prep"),
-    ("jit(potrs)/potrs/backward/triangular_solve", "potrs/backward"),
-    ("jit(posv)/posv/potrf/prep/jit(tril)/iota", "potrf/prep"),
-    ("jit(posv)/posv/solve/potrs", None),
-    ("b", None),
+@pytest.mark.parametrize("op_name, drivers, scope", [
+    ("jit(posv)/posv/potrf/factor/cholesky", DRIVERS, "potrf/factor"),
+    ("jit(posv)/posv/potrf/potrf/factor/cholesky", DRIVERS, "potrf/factor"),
+    ("jit(posv)/posv/potrs/prep/jit(tril)/select_n", DRIVERS, "potrs/prep"),
+    ("jit(potrs)/potrs/backward/triangular_solve", DRIVERS, "potrs/backward"),
+    ("jit(posv)/posv/potrf/prep/jit(tril)/iota", DRIVERS, "potrf/prep"),
+    ("jit(posv)/posv/solve/potrs", DRIVERS, None),
+    ("b", DRIVERS, None),
+    # a phase is looked up under its own driver only
+    ("jit(potrs)/potrs/factor/x", DRIVERS, None),
+    # another configuration's vocabulary names its own drivers
+    ("jit(gesv)/gesv/getrf/select/argmax", LU, "getrf/select"),
+    ("jit(gesv)/gesv/getrf/select/argmax", DRIVERS, None),
+    ("jit(posv)/posv/potrf/factor/cholesky", LU, None),
 ])
-def test_scope_is_the_innermost_driver_phase(op_name, scope):
-    assert scope_of(op_name) == scope
+def test_scope_is_the_innermost_driver_phase(op_name, drivers, scope):
+    assert scope_of(op_name, drivers) == scope
 
 
 def test_instructions_take_their_own_operands_users_or_callers_scope():
-    s = instruction_scopes(HLO)
+    s = instruction_scopes(HLO, DRIVERS)
     assert s["fa"] == "potrf/prep"
     assert s["chol"] == "potrf/factor"
     assert s["fwd"] == "potrs/forward"
@@ -112,7 +128,7 @@ def synthetic(monkeypatch):
 
 
 def test_every_moment_of_device_time_lands_in_one_group(synthetic):
-    r = reduce_scopes("synthetic.xplane.pb", [HLO])
+    r = reduce_scopes("synthetic.xplane.pb", [HLO], DRIVERS)
     assert r["window_s"] == pytest.approx(1000e-9)
     # ops: 100-160, 170-195, 300-310, 315-345, 350-355, 600-650, 950-1000
     assert r["busy_s"] == pytest.approx(230e-9)
@@ -133,7 +149,7 @@ def test_every_moment_of_device_time_lands_in_one_group(synthetic):
 
 
 def test_busy_time_is_the_trace_reductions(synthetic):
-    assert reduce_scopes("synthetic.xplane.pb", [HLO])["busy_s"] == \
+    assert reduce_scopes("synthetic.xplane.pb", [HLO], DRIVERS)["busy_s"] == \
         pytest.approx(reduce_trace("synthetic.xplane.pb")["busy_s"])
 
 
@@ -144,14 +160,17 @@ def test_readings_and_breakdown():
                       "potrf/store": 0.5, "potrs/prep": 0.5,
                       "potrs/forward": 0.75, "potrs/backward": 1.0,
                       UNSCOPED: 0.25}}
-    r = readings(red, 5, {"potrf": 0.1, "potrs": 0.02})
-    assert r == pytest.approx({"wrapper_share": 30.0,
+    r = readings(red, 5, DRIVERS, {"factor": 0.1, "sweep": 0.02})
+    assert r == pytest.approx({"wrapper_share": 30.0, "factor_share": 50.0,
+                               "sweep_share": 17.5,
                                "factor_roofline": 10.0,
                                "sweep_roofline": 100 * 0.02 / 0.35,
                                "host_gap_ms": 100.0})
-    # a window that runs no factorization reads no factor roofline
-    assert "factor_roofline" not in readings(red, 5, {"potrf": None,
-                                                      "potrs": 0.02})
+    # a role whose scopes took no device time reads no roofline, and a
+    # share of 0
+    no_factor = dict(red, groups=dict(red["groups"], **{"potrf/factor": 0.0}))
+    r = readings(no_factor, 5, DRIVERS, {"factor": 0.1, "sweep": 0.02})
+    assert "factor_roofline" not in r and r["factor_share"] == 0.0
     b = breakdown(red, top=2)
     assert b == {"scopes": [["potrf/factor", 5.0], ["potrf/prep", 2.0]],
                  "programs": [["jit_posv", 5, 11.0]]}
@@ -164,7 +183,7 @@ def test_recorded_chip_trace_idles_between_programs():
     runs each step about 1.1-1.3 ms before the host's ``bench.call`` begins
     (the planes' clocks are offset), so the first step's execution lies
     before the host's ``bench.window`` and two executions fall in it."""
-    r = reduce_scopes(SMALL, [])
+    r = reduce_scopes(SMALL, [], DRIVERS)
     assert r["busy_s"] == pytest.approx(reduce_trace(SMALL)["busy_s"])
     assert r["between_programs_s"] >= 3 * 0.002
     assert r["in_program_s"] >= 0
@@ -182,10 +201,54 @@ def test_recorded_scoped_chip_trace():
     factor and the two sweeps are named, little is left unscoped."""
     with open(SCOPED + ".hlo.txt") as f:
         text = f.read()
-    r = reduce_scopes(SCOPED + ".xplane.pb", [text])
+    r = reduce_scopes(SCOPED + ".xplane.pb", [text], DRIVERS)
     assert [p[:2] for p in r["programs"]] == [["jit_posv", 3]]
     assert {"potrf/factor", "potrs/forward", "potrs/backward"} <= set(
         r["groups"])
     assert r["groups"].get(UNSCOPED, 0) < 0.05 * r["busy_s"]
     assert sum(r["groups"].values()) == pytest.approx(r["busy_s"])
     assert r["between_programs_s"] >= 3 * 0.002
+
+
+def legacy_readings(red, steps, least_s):
+    """The four numbers as ``scopes.readings`` computed them before the
+    vocabulary was the configuration's: phases matched by name under
+    either driver, potrf's and potrs's least times."""
+    def phases(*names):
+        return sum(v for g, v in red["groups"].items()
+                   if "/" in g and g.split("/", 1)[1] in names)
+
+    return {"host_gap_ms": red["between_programs_s"] * 1e3 / steps,
+            "wrapper_share": 100 * phases("prep", "mask", "store")
+            / red["busy_s"],
+            "factor_roofline": 100 * least_s["potrf"]
+            / (phases("factor") / steps),
+            "sweep_roofline": 100 * least_s["potrs"]
+            / (phases("forward", "backward") / steps)}
+
+
+@pytest.mark.skipif(not os.path.exists(SCOPED + ".xplane.pb"),
+                    reason="no recorded scoped trace")
+def test_recorded_scoped_trace_readings_by_role():
+    """posv at n=512, 16 right-hand sides, three steps on a v5e: every
+    moment of busy time in a group, none unscoped, and the role readings of
+    the configuration's vocabulary are the phase readings they replace."""
+    with open(SCOPED + ".hlo.txt") as f:
+        text = f.read()
+    red = reduce_scopes(SCOPED + ".xplane.pb", [text], DRIVERS)
+    assert sum(red["groups"].values()) == pytest.approx(red["busy_s"],
+                                                        rel=0.01)
+    assert red["groups"].get(UNSCOPED, 0.0) == 0.0
+    peaks = {"flops_per_s": 197e12, "bytes_per_s": 819e9}
+    least = {r: least_time_s(job(r, 512, 16)["flops"],
+                             job(r, 512, 16)["bytes"], peaks)[0]
+             for r in ("potrf", "potrs")}
+    got = readings(red, 3, DRIVERS, {role: least[routine] for role, routine
+                                     in DENSE["jobs"].items()})
+    want = legacy_readings(red, 3, least)
+    assert {k: got[k] for k in want} == pytest.approx(want, rel=1e-12)
+    # as the reduction read this trace before the vocabulary moved
+    assert want == pytest.approx({"host_gap_ms": 3.9095103333333334,
+                                  "wrapper_share": 21.745171201237547,
+                                  "factor_roofline": 1.394619861075619,
+                                  "sweep_roofline": 2.871458990124009})
