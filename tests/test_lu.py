@@ -110,6 +110,72 @@ def test_getrf_tntpiv_pp_matches_lapack_pivots(rng):
     assert np.allclose(np.asarray(lu_arr), lu_ref, atol=1e-12)
 
 
+# shapes whose full-width panels roll into runs of more than one panel
+# (more than _MIN_RUNS panels), with a ragged last panel, ib < nb, tall and
+# wide
+ROLLED = [(163, 163, 8, 8), (163, 163, 8, 4), (200, 150, 8, 8),
+          (150, 203, 8, 4)]
+
+
+@pytest.mark.parametrize("scheme", ["tournament", "pp"])
+@pytest.mark.parametrize("m,n,nb,ib", ROLLED)
+def test_getrf_tntpiv_rolled_panel_runs(rng, m, n, nb, ib, scheme):
+    """The rolled panel loop (runs of panels, each one fori_loop on windows
+    padded past the matrix): a factorization of A[perm], perm a
+    permutation, for both panel schemes."""
+    assert max(lu_mod._panel_runs(min(m, n) // nb)) > 1
+    a = _gen(rng, m, n)
+    lu_arr, perm, info = linalg.getrf(
+        a, {"method_lu": "calu", "block_size": nb, "inner_blocking": ib,
+            "lu_panel": scheme})
+    assert int(info) == 0
+    assert _check_lu(a, lu_arr, perm) < 1e-11
+    assert sorted(np.asarray(perm).tolist()) == list(range(m))
+
+
+@pytest.mark.parametrize("n", [24, 136])
+def test_getrf_tntpiv_pp_one_panel_is_partial_pivoting(rng, n):
+    """ib == nb == n: pp-CALU's one panel LU is classic partial pivoting,
+    LAPACK's pivots and factor."""
+    import scipy.linalg as sla
+
+    a = _gen(rng, n, n)
+    lu_arr, perm, _ = linalg.getrf(
+        a, {"method_lu": "calu", "block_size": n, "inner_blocking": n,
+            "lu_panel": "pp"})
+    lu_ref, piv = sla.lu_factor(a)
+    assert np.array_equal(np.asarray(perm), lu_mod.pivots_to_perm(piv + 1))
+    assert np.allclose(np.asarray(lu_arr), lu_ref, atol=1e-12)
+
+
+def _lowered_ops(n, nb=256):
+    import re
+
+    text = lu_mod._getrf_tntpiv_fn(n, n, nb, nb, "float32").lower(
+        jax.ShapeDtypeStruct((n, n), jnp.float32)).as_text()
+    return len(re.findall(r"= (?:stablehlo|chlo|mhlo)\.", text))
+
+
+def test_getrf_tntpiv_program_does_not_grow_with_panels():
+    """The rolled factorization's program is the size of its runs, not of
+    its panels: n=4096 (16 panels of 256) lowers to at most 1.25x the
+    operations of n=2048 (8 panels); unrolled it was twice as many."""
+    assert _lowered_ops(4096) <= 1.25 * _lowered_ops(2048)
+
+
+def test_getrf_tntpiv_flops_at_n2048():
+    """XLA's count of the compiled factorization at n=2048, nb=256 (8 runs
+    of one panel, so every loop body counts once, as it runs) lies within
+    1.15x of LAPACK's 2n^3/3."""
+    from slate_tpu.testing import cost_analysis_dict
+
+    n = 2048
+    comp = lu_mod._getrf_tntpiv_fn(n, n, 256, 256, "float32").lower(
+        jax.ShapeDtypeStruct((n, n), jnp.float32)).compile()
+    flops = cost_analysis_dict(comp)["flops"]
+    assert 0.5 * 2 * n ** 3 / 3 <= flops <= 1.15 * 2 * n ** 3 / 3
+
+
 def test_getrf_bad_lu_panel_raises(rng):
     """lu_panel is validated on EVERY getrf path, not silently ignored
     (parity-audit behavior contract) — including the default PartialPiv

@@ -218,3 +218,69 @@ def test_eager_trace_block_lands_on_the_profiler_clock(tmp_path):
     names = {ev.name for plane in ProfileData.from_file(path).planes
              for line in plane.lines for ev in line.events}
     assert "eager_region" in names
+
+
+LU_PHASE = re.compile(r"(?:^|/)(getrf/(?:select|swap|panel|update|info)|"
+                      r"getrs/(?:permute|forward|backward|store))(?=/|$)")
+LU_N, LU_NB = 512, 128
+INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (?:\(.*?\)|\S+) "
+                         r"([\w\-]+)\(")
+
+
+def lu_phase(op_name):
+    """The innermost ``getrf/<phase>`` or ``getrs/<phase>`` of an op_name."""
+    found = LU_PHASE.findall(op_name or "")
+    return found[-1] if found else None
+
+
+@pytest.fixture(scope="module")
+def gesv_text():
+    """slate's gesv through the tournament-pivoted LU, compiled at n=512,
+    nb=ib=128, as the dense general solve's benchmark program calls it."""
+    def gesv(a, b):
+        B = slate.Matrix.from_array(b)
+        _, _, info = slate.gesv(slate.Matrix.from_array(a), B,
+                                {"method_lu": "calu", "block_size": LU_NB})
+        return B.array, info
+
+    return compiled_text(gesv, jax.ShapeDtypeStruct((LU_N, LU_N), jnp.float32),
+                         B_SPEC)
+
+
+def lu_ops(text):
+    """``{(opcode or custom-call target, phase)}`` of every instruction."""
+    out = set()
+    for line in text.splitlines():
+        m = INSTRUCTION.match(line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if m and op:
+            target = re.search(r'custom_call_target="([^"]*)"', line)
+            out.add((target.group(1) if target else m.group(2),
+                     lu_phase(op.group(1))))
+    return out
+
+
+@pytest.mark.parametrize("op, where", [
+    ("dot", "getrf/update"),                 # the trailing update
+    ("lapack_sgetrf_ffi", "getrf/select"),   # the tournament's merge LUs
+    ("gather", "getrf/swap"),                # the dirty-row exchange
+    ("scatter", "getrf/swap"),
+    ("lapack_strsm_ffi", "getrf/panel"),     # L21 and U12
+    ("lapack_strsm_ffi", "getrs/forward"),   # the sweeps' diagonal blocks
+    ("lapack_strsm_ffi", "getrs/backward"),
+])
+def test_gesv_phases_name_the_lu_operations(gesv_text, op, where):
+    assert (op, where) in lu_ops(gesv_text)
+
+
+def test_gesv_kernels_all_lie_in_lu_phases(gesv_text):
+    """Every kernel traced from the program is inside a getrf or getrs
+    phase, the rolled panel loops' own control included; the compiler's
+    relayouts of arguments carry the argument's name or nothing."""
+    traced = {k: v for k, v in kernels(gesv_text).items()
+              if v and v.startswith("jit(")}
+    assert traced
+    assert {k: v for k, v in traced.items() if lu_phase(v) is None} == {}
+    assert {"getrf/select", "getrf/swap", "getrf/panel", "getrf/update",
+            "getrf/info", "getrs/permute", "getrs/forward",
+            "getrs/backward"} <= {lu_phase(v) for v in traced.values()}
