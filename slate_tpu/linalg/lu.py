@@ -46,7 +46,8 @@ from ..robust import (RetryPolicy, Rung, SolveReport, first_bad_index, inject,
                       run_ladder)
 from ..utils.trace import trace_block, trace_event
 from .chol import _ir_solve
-from ..obs import instrument
+from ..obs import counter, instrument
+from ..ops.pallas_tntpiv import merge_winners
 
 
 # ---------------------------------------------------------------------------
@@ -336,13 +337,33 @@ def _phase(name):
     return trace_block("getrf/" + name)
 
 
+def _merge_winners(v2):
+    """The rows partial pivoting promotes in each (2w, w) block of ``v2``
+    (one block, or a batch), in pivot order: ``lax.linalg.lu(v2)[2][...,
+    :w]``.  The Pallas kernel, which steps a level's merges together and
+    forms no factor, takes them where it applies -- f32 on a TPU, ``w`` a
+    multiple of 128; elsewhere XLA's LU, which steps through a batch one
+    block at a time.  Counts the merges at trace time, by implementation,
+    in ``lu_tournament_merges``."""
+    w = v2.shape[-1]
+    impl = ("pallas" if _operand_device(v2).platform == "tpu"
+            and v2.dtype == jnp.float32 and w % 128 == 0 else "xla")
+    counter("lu_tournament_merges",
+            "tournament pair merges traced, by implementation").inc(
+                v2.size // (2 * w * w), impl=impl)
+    if impl == "pallas":
+        return merge_winners(v2.reshape(-1, 2 * w, w)).reshape(
+            v2.shape[:-2] + (w,))
+    return lax.linalg.lu(v2)[2][..., :w]
+
+
 def _tournament_panel(panel, nb, live):
     """Select nb pivot rows of a tall panel by tournament (getrf_tntpiv.cc panel:
     block-local partially-pivoted LUs, then a binary reduction tree over winners;
     internal_getrf_tntpiv.cc / Tile_getrf_tntpiv.hh semantics, re-expressed as a
-    static tree of lax.linalg.lu calls).  The leaves are the panel's full
-    nb-row blocks; a ragged tail block joins the root merge, which orders
-    the winners.
+    static tree of batched pair merges, :func:`_merge_winners`).  The
+    leaves are the panel's full nb-row blocks; a ragged tail block joins
+    the root merge, which orders the winners.
 
     Only the panel's first ``live`` rows (a count that a rolled panel loop
     traces) are candidates, and the rows past them are zero.  The tree runs
@@ -360,17 +381,15 @@ def _tournament_panel(panel, nb, live):
     V = jnp.where((rows < nfull * nb)[:, None], panel[:leaves * nb],
                   0).reshape(leaves, nb, w)
     I = rows.reshape(leaves, nb)
-    # uniform leaves (nb rows each) reduce as ONE batched LU per tree level —
-    # TPU executes ops sequentially, so the reference's independent per-pair
-    # merges must be a batch, not a Python loop of separate lu calls (this
-    # halved the measured CALU time at the n=16384 bench config)
+    # uniform leaves (nb rows each) reduce as ONE batched merge per tree
+    # level -- TPU executes ops sequentially, so the reference's independent
+    # per-pair merges must be a batch, not a Python loop of separate calls
     while V.shape[0] > 1:
         nblk = V.shape[0]
         half = nblk // 2
         V2 = jnp.concatenate([V[0:2 * half:2], V[1:2 * half:2]], axis=1)
         I2 = jnp.concatenate([I[0:2 * half:2], I[1:2 * half:2]], axis=1)
-        _, _, perm = lax.linalg.lu(V2)          # batched pair merges
-        take = perm[:, :nb]
+        take = _merge_winners(V2)               # batched pair merges
         V2 = jnp.take_along_axis(V2, take[:, :, None], axis=1)
         I2 = jnp.take_along_axis(I2, take, axis=1)
         if nblk % 2:
@@ -382,8 +401,7 @@ def _tournament_panel(panel, nb, live):
     padded = jnp.concatenate([panel, jnp.zeros((nb, w), panel.dtype)])
     sub = jnp.concatenate([sub, lax.dynamic_slice_in_dim(padded, tail, nb)])
     idx = jnp.concatenate([idx, tail + jnp.arange(nb)])
-    _, _, perm = lax.linalg.lu(sub)
-    return jnp.take(idx, perm[:nb])
+    return jnp.take(idx, _merge_winners(sub))
 
 
 #: A rolled factorization has at least this many panel runs (each one loop

@@ -7,6 +7,9 @@ compile is of a real-width program:
 
 * the five Pallas norm launches at 16384^2 f32 (genorm fro/max/one/inf and
   col_norms_max), each checked for ``tpu_custom_call`` in the compiled HLO;
+* the tournament's Pallas merge kernel at the benchmark's width (a level of
+  one and of 32 stacked 512x256 blocks), and the rolled tournament-pivoted
+  LU that calls it, which must keep its matrix row-major;
 * the batched serve programs (``gesv``/``posv``/``gels`` cores, vmapped by
   the serve layer's own builder) at the largest bucket the serve workload
   generator draws.
@@ -15,6 +18,8 @@ The topology is described inside a module-scoped fixture, never at import
 (only one process may load the TPU library; see the on-chip-measurement
 guide), so every xdist worker collects the same tests.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +59,25 @@ def mosaic(monkeypatch):
     jax.clear_caches()
 
 
+@pytest.fixture
+def merge_kernel(monkeypatch):
+    """Steer the tournament to the Pallas merge kernel's TPU lowering: its
+    operands read as on a TPU, and the kernel compiles through Mosaic."""
+    from types import SimpleNamespace
+
+    from slate_tpu.linalg import lu
+    from slate_tpu.ops import pallas_tntpiv
+
+    monkeypatch.setattr(pallas_tntpiv, "_interpret", lambda: False)
+    monkeypatch.setattr(lu, "_operand_device",
+                        lambda a: SimpleNamespace(platform="tpu"))
+    lu._getrf_tntpiv_fn.cache_clear()
+    jax.clear_caches()
+    yield pallas_tntpiv
+    lu._getrf_tntpiv_fn.cache_clear()
+    jax.clear_caches()
+
+
 def _compile(fn, *args):
     # the chip runs with x64 off (conftest turns it on for the CPU tests);
     # Mosaic refuses the i64 block indices x64 would give the kernels
@@ -90,3 +114,27 @@ def test_serve_program_compiles_for_v5e(one_chip, routine):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < 16 * 10**9           # fits one v5e's 16 GB of HBM
+
+
+@pytest.mark.parametrize("nblk", [1, 32])
+def test_merge_kernel_compiles_for_v5e(one_chip, merge_kernel, nblk):
+    v2 = jax.ShapeDtypeStruct((nblk, 512, 256), jnp.float32,
+                              sharding=one_chip)
+    assert "tpu_custom_call" in _compile(merge_kernel.merge_winners,
+                                         v2).as_text()
+
+
+def test_getrf_tntpiv_with_merge_kernel_keeps_row_major(one_chip,
+                                                        merge_kernel):
+    """The rolled factorization that selects through the merge kernel holds
+    its matrix row-major: a kernel operand transposed outside the kernel
+    had the compiler keep the loop's matrix column-major, with two copies
+    of the whole matrix a panel (~250 ms a solve at n=16384 on a v5e)."""
+    from slate_tpu.linalg import lu
+
+    n = 1024
+    a = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
+    text = _compile(lu._getrf_tntpiv_fn(n, n, 256, 256, "float32"),
+                    a).as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(rf"f32\[{n},{n}\]{{0,1[^}}]*}} copy\(", text)

@@ -176,6 +176,113 @@ def test_getrf_tntpiv_flops_at_n2048():
     assert 0.5 * 2 * n ** 3 / 3 <= flops <= 1.15 * 2 * n ** 3 / 3
 
 
+def _replay_max_l(block, winners):
+    """The largest |l| of each step of an f64 no-pivot LU of ``block`` with
+    ``winners`` moved to its top in their order: at most 1 where they are a
+    partial-pivot choice, near 1 at a near-tie."""
+    rest = np.setdiff1d(np.arange(block.shape[0]), winners)
+    a = block.astype(np.float64)[np.concatenate([winners, rest])]
+    worst = np.empty(block.shape[1])
+    for k in range(block.shape[1]):
+        l = a[k + 1:, k] / a[k, k]
+        worst[k] = np.max(np.abs(l))
+        a[k + 1:, k + 1:] -= np.outer(l, a[k, k + 1:])
+    return worst
+
+
+@pytest.mark.parametrize("w", [128, 256])
+@pytest.mark.parametrize("nblk", [1, 3, 32])
+def test_merge_kernel_is_partial_pivoting(rng, nblk, w):
+    """The Pallas merge kernel (interpreted here) picks, for each stacked
+    (2w, w) block, rows partial pivoting may pick, in its order, and the
+    rows XLA's LU picks, but where a near-tie lets rounding choose."""
+    from slate_tpu.ops.pallas_tntpiv import merge_winners
+
+    v2 = rng.standard_normal((nblk, 2 * w, w)).astype(np.float32)
+    got = np.asarray(merge_winners(jnp.asarray(v2)))
+    want = np.asarray(jax.lax.linalg.lu(jnp.asarray(v2))[2])[:, :w]
+    assert got.shape == (nblk, w)
+    for block, g, x in zip(v2, got, want):
+        worst = _replay_max_l(block, g)
+        assert worst.max() <= 1 + 1e-5
+        if set(g) != set(x):
+            k = np.argmax(g != x)           # where the two orders part
+            assert worst[k] >= 1 - 1e-4, (k, worst[k])
+
+
+@pytest.mark.parametrize("zero", [0, 1])
+def test_merge_kernel_zero_half_returns_other_half(rng, zero):
+    """A stack whose one half is zero (a leaf past the live rows) returns
+    the other half's rows."""
+    from slate_tpu.ops.pallas_tntpiv import merge_winners
+
+    w = 128
+    v2 = rng.standard_normal((2, 2 * w, w)).astype(np.float32)
+    v2[:, zero * w:(zero + 1) * w] = 0
+    got = np.asarray(merge_winners(jnp.asarray(v2)))
+    other = set(range((1 - zero) * w, (2 - zero) * w))
+    assert all(set(g) == other for g in got)
+
+
+@pytest.fixture
+def platform(monkeypatch):
+    """Set the platform the tournament sees its operands on: "tpu" routes
+    f32 merges of 128-multiple widths to the Pallas kernel (interpreted on
+    this CPU).  The factorization's program is rebuilt to retrace."""
+    from types import SimpleNamespace
+
+    def use(name):
+        monkeypatch.setattr(lu_mod, "_operand_device",
+                            lambda a: SimpleNamespace(platform=name))
+        lu_mod._getrf_tntpiv_fn.cache_clear()
+
+    yield use
+    lu_mod._getrf_tntpiv_fn.cache_clear()
+
+
+def test_getrf_tntpiv_merge_kernel_matches_xla_path(rng, platform):
+    """getrf_tntpiv at f32 n=1024, nb=ib=256 selects through the merge
+    kernel and through XLA's LU: both factor A[perm], perm a permutation,
+    with residuals within 2x of each other; the trace counts each path's
+    merges under its ``impl``."""
+    from slate_tpu import obs
+
+    n = 1024
+    a = rng.standard_normal((n, n)).astype(np.float32)
+    merges = obs.counter("lu_tournament_merges")
+    resid = {}
+    for name, impl in (("tpu", "pallas"), ("cpu", "xla")):
+        platform(name)
+        before = merges.value(impl=impl)
+        lu_arr, perm, info = linalg.getrf(
+            a, {"method_lu": "calu", "block_size": 256,
+                "inner_blocking": 256})
+        assert merges.value(impl=impl) > before
+        assert int(info) == 0
+        assert sorted(np.asarray(perm).tolist()) == list(range(n))
+        resid[impl] = _check_lu(a.astype(np.float64),
+                                np.asarray(lu_arr, np.float64), perm)
+        assert resid[impl] < 1e-5
+    assert 0.5 <= resid["pallas"] / resid["xla"] <= 2.0, resid
+
+
+def test_merge_kernel_not_taken_off_f32(rng, platform):
+    """On a TPU, f64 merges stay with XLA's LU: the trace counts them
+    under ``impl="xla"`` and none under ``"pallas"``."""
+    from slate_tpu import obs
+
+    n = 512
+    a = rng.standard_normal((n, n))
+    merges = obs.counter("lu_tournament_merges")
+    platform("tpu")
+    before = {i: merges.value(impl=i) for i in ("pallas", "xla")}
+    lu_arr, perm, info = linalg.getrf(
+        a, {"method_lu": "calu", "block_size": 256, "inner_blocking": 256})
+    assert merges.value(impl="xla") > before["xla"]
+    assert merges.value(impl="pallas") == before["pallas"]
+    assert _check_lu(a, lu_arr, perm) < 1e-11
+
+
 def test_getrf_bad_lu_panel_raises(rng):
     """lu_panel is validated on EVERY getrf path, not silently ignored
     (parity-audit behavior contract) — including the default PartialPiv
